@@ -328,7 +328,9 @@ def shard_ab(arch: str, *, prompt_len: int, quick: bool = False) -> dict:
 
     if quick:
         return {}
-    env = dict(os.environ,
+    # the children simulate CPU meshes by design: pin them to the CPU so
+    # they never reach for an accelerator this process already holds
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
                XLA_FLAGS="--xla_force_host_platform_device_count=8")
     r = subprocess.run(
         [sys.executable, "-m", "benchmarks.serve_decode",
@@ -354,7 +356,7 @@ def shard_ab(arch: str, *, prompt_len: int, quick: bool = False) -> dict:
                    "--arch", arch, "--shape", "decode_32k",
                    "--json", tmp.name] + (["--multi-pod"] if mp else [])
             rr = subprocess.run(cmd, capture_output=True, text=True,
-                                env={**os.environ})
+                                env=dict(os.environ, JAX_PLATFORMS="cpu"))
             try:
                 with open(tmp.name) as f:
                     rec = json.load(f)
@@ -847,6 +849,8 @@ def main(argv=None):
                          "and print SHARD_JSON instead of benchmarking")
     ap.add_argument("--out", default=OUT)
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.shard_probe:
         out = shard_probe(args.shard_probe, prompt_len=args.prompt_len)
         print("SHARD_JSON " + json.dumps(out))

@@ -34,18 +34,26 @@ __all__ = [
     "cast_and_pack", "tp_elementwise", "storage_dtype", "set_mixed_dot",
 ]
 
-# Emit true mixed-precision dots (bf16 x bf16 -> f32, the MXU's native
-# expanding FMA) in the HLO.  XLA:CPU can *compile* these but its thunk
-# runtime cannot execute every layout, so execution paths on CPU default to
-# upcasting operands first (bit-identical results — narrow->f32 casts are
-# exact).  The dry-run (lower/compile only) enables this so the lowered HLO
-# and its cost analysis match what a TPU would run.
-_MIXED_DOT = False
+# Native-mode contractions emit true mixed-precision dots (bf16 x bf16 ->
+# f32 via ``preferred_element_type``, the MXU's native expanding FMA) on
+# every platform but the CPU.  XLA:CPU can *compile* these but its thunk
+# runtime cannot execute every layout, so CPU execution upcasts the operands
+# first (bit-identical results — narrow->f32 casts are exact).  ``None``
+# lets the platform decide; the dry-run, which only lowers and compiles on
+# CPU host devices, forces ``True`` so its HLO and cost analysis match what
+# a TPU runs.
+_MIXED_DOT: Optional[bool] = None
 
 
-def set_mixed_dot(enable: bool) -> None:
+def set_mixed_dot(enable: Optional[bool]) -> None:
     global _MIXED_DOT
     _MIXED_DOT = enable
+
+
+def _mixed_dot() -> bool:
+    if _MIXED_DOT is not None:
+        return _MIXED_DOT
+    return jax.default_backend() != "cpu"
 
 
 def storage_dtype(fmt, mode: str):
@@ -138,7 +146,7 @@ def tp_einsum(spec: str, a, b, policy, *, out_fmt=None, use_ste: bool = True,
             # cross-shard partial-sum all-reduce then runs in the narrow
             # format (per-tile MXU accumulation is still f32)
             acc_dt = out.native_dtype
-        if _MIXED_DOT:
+        if _mixed_dot():
             r = jnp.einsum(spec, sa, sb, preferred_element_type=acc_dt,
                            precision=precision)
         else:

@@ -294,18 +294,12 @@ def _sweep(op: str, shape: Sequence[int], dtype, make_fn, *,
     return winner, timings
 
 
-def _resolve_interpret(interpret) -> bool:
-    """None -> interpret on CPU, compiled elsewhere.  Winners are keyed by
-    backend, so a sweep must time what that backend will actually run —
-    timing the interpreter on TPU would memoize garbage under the tpu key."""
-    return jax.default_backend() == "cpu" if interpret is None else interpret
-
-
 def autotune_matmul(m: int, k: int, n: int, dtype=jnp.float32, *,
                     interpret: Optional[bool] = None, repeats: int = 3,
                     persist: bool = True, verbose: bool = False):
+    # interpret=None resolves from the platform in the kernel wrappers:
+    # winners are keyed by backend, so a sweep times what it will run
     from . import ops as kops
-    interpret = _resolve_interpret(interpret)
     a = jax.random.normal(jax.random.key(0), (m, k), jnp.float32).astype(dtype)
     b = jax.random.normal(jax.random.key(1), (k, n), jnp.float32).astype(dtype)
     mk = lambda blk: functools.partial(kops.tp_matmul, a, b, block=blk,
@@ -319,7 +313,6 @@ def autotune_attention(sq: int, skv: int, d: int, heads: int = 4,
                        repeats: int = 3, persist: bool = True,
                        verbose: bool = False):
     from . import ops as kops
-    interpret = _resolve_interpret(interpret)
     ks = jax.random.split(jax.random.key(0), 3)
     q = jax.random.normal(ks[0], (1, heads, sq, d), jnp.float32).astype(dtype)
     k = jax.random.normal(ks[1], (1, heads, skv, d), jnp.float32).astype(dtype)
@@ -336,7 +329,6 @@ def autotune_decode(group: int, smax: int, d: int, heads: int = 4,
                     repeats: int = 3, persist: bool = True,
                     verbose: bool = False):
     from . import ops as kops
-    interpret = _resolve_interpret(interpret)
     ks = jax.random.split(jax.random.key(0), 3)
     q = jax.random.normal(ks[0], (1, heads * group, 1, d), jnp.float32)
     k = jax.random.normal(ks[1], (1, heads, smax, d), jnp.float32)

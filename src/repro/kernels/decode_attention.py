@@ -80,10 +80,28 @@ N_FLAGS = 4
 def _flag_counts(x_ref, fmt, src_dtype, live):
     """Per-tile OF/UF/NX/NV counts of one CONV site, masked by ``live``
     (liveness along the leading tile axis — dead/padded slots contribute
-    zero).  Returns an int32 [4] vector."""
+    zero).  Returns a tuple of four int32 scalars."""
     _, of, uf, nx, nv = _widen_flags(x_ref, fmt, src_dtype)
-    return jnp.stack([jnp.sum((f & live).astype(jnp.int32))
-                      for f in (of, uf, nx, nv)])
+    return tuple(jnp.sum((f & live).astype(jnp.int32))
+                 for f in (of, uf, nx, nv))
+
+
+def _put_debug_row(ref, i, vals):
+    """Set row ``i`` (a grid id) of a debug output block.
+
+    Debug outputs are whole-row blocks ``[1, n, C]`` kept resident across
+    one head row's grid steps — Mosaic's tiling rule wants a block's last
+    two dims (8, 128)-aligned or spanning the array, which per-cell
+    ``(1, 1)`` blocks are not — and every step selects its own row in:
+    ``vals`` holds the ``C`` per-channel scalars.  Every row is written by
+    some step, so no initialization is needed."""
+    blk = ref[0]
+    rows = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 0)
+    cols = jax.lax.broadcasted_iota(jnp.int32, blk.shape, 1)
+    new = vals[-1]
+    for c in range(len(vals) - 2, -1, -1):
+        new = jnp.where(cols == c, vals[c], new)
+    ref[0] = jnp.where(rows == i, new, blk)
 
 
 def softcap_scores(s, cap: float):
@@ -171,7 +189,7 @@ def _decode_kernel(len_ref, *args, nk: int, bk: int, paged: bool,
                     jnp.where(l == 0.0, 1.0, l)).astype(out_dtype)
 
     if debug_visits:
-        visits_ref[0, 0] = active.astype(jnp.int32)
+        _put_debug_row(visits_ref, j, (active.astype(jnp.int32),))
     if debug_flags:
         # Flag accumulation mirrors debug_visits: both passes write the same
         # (h, j) cell and the accumulate pass (ip == 1) writes last, when
@@ -182,12 +200,13 @@ def _decode_kernel(len_ref, *args, nk: int, bk: int, paged: bool,
         # write zeros: dead/padded cache slots contribute nothing.
         live = (j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
                 ) < kvl
-        cnts = (_flag_counts(k_ref[0], kv_fmt, src_dtype, live)
-                + _flag_counts(v_ref[0], kv_fmt, src_dtype, live))
+        kc = _flag_counts(k_ref[0], kv_fmt, src_dtype, live)
+        vc = _flag_counts(v_ref[0], kv_fmt, src_dtype, live)
         qc = _flag_counts(q_ref[0], q_fmt, src_dtype,
                           jnp.ones((1, 1), jnp.bool_))
-        cnts = cnts + jnp.where(j == 0, qc, 0)
-        flags_ref[0, 0, :] = jnp.where(active, cnts, 0)
+        _put_debug_row(flags_ref, j, tuple(
+            jnp.where(active, a + b + jnp.where(j == 0, c, 0), 0)
+            for a, b, c in zip(kc, vc, qc)))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -271,25 +290,23 @@ def decode_attention_pallas(q, k, v, kv_len, block_table=None, *,
         # once, K twice (the cost stated in the module docstring).
         v_map = lambda h, p, j, kvl, bt: (bt[h, j * p], 0, 0)
         fixed = lambda h, p, j, kvl, bt: (h, 0, 0)
-        vis = lambda h, p, j, kvl, bt: (h, j)
-        flg = lambda h, p, j, kvl, bt: (h, j, 0)
+        row = lambda h, p, j, kvl, bt: (h, 0, 0)
     else:
         scalars = (kvl,)
         k_map = lambda h, p, j, kvl: (h, j, 0)
         v_map = lambda h, p, j, kvl: (h, j * p, 0)   # pinned as above
         fixed = lambda h, p, j, kvl: (h, 0, 0)
-        vis = lambda h, p, j, kvl: (h, j)
-        flg = lambda h, p, j, kvl: (h, j, 0)
+        row = lambda h, p, j, kvl: (h, 0, 0)
     out_shape = [jax.ShapeDtypeStruct((bh, g, d), out_dtype)]
     out_specs = [pl.BlockSpec((1, g, d), fixed)]
     if debug_visits:
         # both passes write the same (h, j) cell with the same value
-        out_shape.append(jax.ShapeDtypeStruct((bh, nk), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1), vis))
+        out_shape.append(jax.ShapeDtypeStruct((bh, nk, 1), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, nk, 1), row))
     if debug_flags:
         # the accumulate pass's write survives (correct V page; see kernel)
         out_shape.append(jax.ShapeDtypeStruct((bh, nk, N_FLAGS), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1, N_FLAGS), flg))
+        out_specs.append(pl.BlockSpec((1, nk, N_FLAGS), row))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(bh, 2, nk),
@@ -307,4 +324,7 @@ def decode_attention_pallas(q, k, v, kv_len, block_table=None, *,
     out = pl.pallas_call(
         kern, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
     )(*scalars, q, k, v)
+    out = list(out)
+    if debug_visits:
+        out[1] = out[1][..., 0]
     return tuple(out) if (debug_visits or debug_flags) else out[0]

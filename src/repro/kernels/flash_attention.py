@@ -57,7 +57,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..core.formats import get_format
-from .decode_attention import N_FLAGS, _flag_counts, softcap_scores
+from .decode_attention import (N_FLAGS, _flag_counts, _put_debug_row,
+                               softcap_scores)
 from .quant_common import widen as _widen
 
 NEG_INF = -1e30
@@ -176,7 +177,7 @@ def _attn_kernel(kvl_ref, qi_ref, ki_ref, ff_ref, lf_ref, *args, bq: int,
                     jnp.where(l == 0.0, 1.0, l)).astype(out_dtype)
 
     if debug_visits:
-        visits_ref[0, 0] = active.astype(jnp.int32)
+        _put_debug_row(visits_ref, step, (active.astype(jnp.int32),))
     if debug_flags:
         # Per-VISIT flag counts (like debug_visits): each scheduled step's
         # K/V tiles are charged to its own (h, step) cell, masked to the
@@ -186,12 +187,14 @@ def _attn_kernel(kvl_ref, qi_ref, ki_ref, ff_ref, lf_ref, *args, bq: int,
         # stored-data CONV sites (q/k/v), not recomputed probabilities.
         live = (ik * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
                 ) < kvl
-        cnts = (_flag_counts(k_ref[0], src_fmt, src_dtype, live)
-                + _flag_counts(v_ref[0], src_fmt, src_dtype, live))
+        kc = _flag_counts(k_ref[0], src_fmt, src_dtype, live)
+        vc = _flag_counts(v_ref[0], src_fmt, src_dtype, live)
         qc = _flag_counts(q_ref[0], src_fmt, src_dtype,
                           jnp.ones((1, 1), jnp.bool_))
-        cnts = cnts + jnp.where(ff_ref[step] == 1, qc, 0)
-        flags_ref[0, 0, :] = jnp.where(active, cnts, 0)
+        first = ff_ref[step] == 1
+        _put_debug_row(flags_ref, step, tuple(
+            jnp.where(active, a + b + jnp.where(first, c, 0), 0)
+            for a, b, c in zip(kc, vc, qc)))
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -283,25 +286,25 @@ def flash_attention_pallas(q, k, v, kv_len=None, block_table=None, *,
         q_map = lambda h, s, kvl, qi, ki, ff, lf, bt: (h, qi[s], 0)
         kv_map = lambda h, s, kvl, qi, ki, ff, lf, bt, g=group: \
             (bt[h // g, ki[s]], 0, 0)
-        vis_map = lambda h, s, kvl, qi, ki, ff, lf, bt: (h, s)
-        flg_map = lambda h, s, kvl, qi, ki, ff, lf, bt: (h, s, 0)
+        row_map = lambda h, s, kvl, qi, ki, ff, lf, bt: (h, 0, 0)
     else:
         scalars = (kvl, jnp.asarray(qi), jnp.asarray(ki), jnp.asarray(ff),
                    jnp.asarray(lf))
         q_map = lambda h, s, kvl, qi, ki, ff, lf: (h, qi[s], 0)
         kv_map = lambda h, s, kvl, qi, ki, ff, lf, g=group: \
             (h // g, ki[s], 0)
-        vis_map = lambda h, s, kvl, qi, ki, ff, lf: (h, s)
-        flg_map = lambda h, s, kvl, qi, ki, ff, lf: (h, s, 0)
+        row_map = lambda h, s, kvl, qi, ki, ff, lf: (h, 0, 0)
     out_shape = [jax.ShapeDtypeStruct((bh, sq, dv), out_dtype)]
     out_specs = [pl.BlockSpec((1, bq, dv), q_map)]
     if debug_visits:
-        out_shape.append(jax.ShapeDtypeStruct((bh, n_steps), jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1), vis_map))
+        # whole-row debug blocks, resident across the row's steps (see
+        # decode_attention._put_debug_row)
+        out_shape.append(jax.ShapeDtypeStruct((bh, n_steps, 1), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, n_steps, 1), row_map))
     if debug_flags:
         out_shape.append(jax.ShapeDtypeStruct((bh, n_steps, N_FLAGS),
                                               jnp.int32))
-        out_specs.append(pl.BlockSpec((1, 1, N_FLAGS), flg_map))
+        out_specs.append(pl.BlockSpec((1, n_steps, N_FLAGS), row_map))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(bh, n_steps),
@@ -319,4 +322,7 @@ def flash_attention_pallas(q, k, v, kv_len=None, block_table=None, *,
     out = pl.pallas_call(
         kern, grid_spec=grid_spec, out_shape=out_shape, interpret=interpret,
     )(*scalars, q, k, v)
+    out = list(out)
+    if debug_visits:
+        out[1] = out[1][..., 0]
     return tuple(out) if (debug_visits or debug_flags) else out[0]

@@ -1,9 +1,10 @@
 """Public jit'd wrappers around the Pallas kernels.
 
 These handle shape padding/alignment, policy plumbing, and head flattening
-so the model code can call them like ordinary jnp ops.  ``interpret=True``
-everywhere in this container (CPU); on real TPUs the same code runs compiled
-by flipping the flag (kept as an argument end-to-end).
+so the model code can call them like ordinary jnp ops.  Every wrapper takes
+``interpret=None``, resolved from the platform (``resolve_interpret``): the
+Pallas interpreter on CPU, compiled Mosaic kernels on a TPU.  Passing a bool
+pins the mode (tests, autotuning).
 """
 from __future__ import annotations
 
@@ -34,8 +35,15 @@ def _pad_to(x, mults, axes):
     return (jnp.pad(x, pads), True) if padded else (x, False)
 
 
+def resolve_interpret(interpret: Optional[bool]) -> bool:
+    """``None`` -> interpret on CPU, compiled kernels elsewhere."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    return interpret
+
+
 def tp_matmul(a, b, *, policy=None, out_fmt=None, block=None,
-              interpret: bool = True):
+              interpret: Optional[bool] = None):
     """Policy-aware Pallas matmul: a [.., M, K] @ b [K, N]."""
     policy = get_policy(policy) if policy is not None else get_policy("tp_bf16")
     mp = policy.matmul
@@ -60,13 +68,14 @@ def tp_matmul(a, b, *, policy=None, out_fmt=None, block=None,
         qname = mp.src_fmt.name
         out_dtype = jnp.float32
     r = tp_matmul_pallas(a2, b2, block=(bm, bk, bn), out_dtype=out_dtype,
-                         quant_fmt_name=qname, interpret=interpret)
+                         quant_fmt_name=qname,
+                         interpret=resolve_interpret(interpret))
     r = r[:m, :n]
     return r.reshape(*lead, a.shape[-2], n) if lead else r
 
 
 def tp_quantize(x, *, fmt, stochastic: bool = False, key=None,
-                out_dtype=None, interpret: bool = True):
+                out_dtype=None, interpret: Optional[bool] = None):
     """Pallas-fused quantization of a 2D array (CONV block)."""
     fmt = get_format(fmt)
     rows, cols = x.shape
@@ -77,12 +86,12 @@ def tp_quantize(x, *, fmt, stochastic: bool = False, key=None,
         rbits = jax.random.bits(key, x2.shape, jnp.uint32)
     r = tp_quantize_pallas(x2, rbits, fmt_name=fmt.name, stochastic=stochastic,
                            out_dtype=out_dtype or jnp.float32,
-                           interpret=interpret)
+                           interpret=resolve_interpret(interpret))
     return r[:rows, :cols]
 
 
 def cast_and_pack(a, b, *, fmt, stochastic: bool = False, key=None,
-                  interpret: bool = True):
+                  interpret: Optional[bool] = None):
     fmt = get_format(fmt)
     rows, cols = a.shape
     a2, _ = _pad_to(a, (256, 128), (0, 1))
@@ -92,7 +101,8 @@ def cast_and_pack(a, b, *, fmt, stochastic: bool = False, key=None,
         assert key is not None
         rbits = jax.random.bits(key, a2.shape, jnp.uint32)
     r = cast_and_pack_pallas(a2, b2, rbits, fmt_name=fmt.name,
-                             stochastic=stochastic, interpret=interpret)
+                             stochastic=stochastic,
+                             interpret=resolve_interpret(interpret))
     return r[:rows, :2 * cols]
 
 
@@ -184,8 +194,7 @@ def flash_attention(q, k, v, *, kv_len=None, policy=None,
     steps; per-visit semantics — docs/KERNELS.md) from the kernel's
     ``debug_flags`` counters.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     policy = get_policy(policy) if policy is not None else get_policy("tp_bf16")
     mp = policy.matmul
     if policy.mode == "native":
@@ -272,16 +281,14 @@ def decode_attention(q, k, v, *, kv_len, policy=None,
 
     ``interpret=None`` auto-resolves: interpret on CPU, compiled on real
     accelerators — this wrapper sits on the serving hot path (behind
-    ``cfg.decode_backend``), so it must not silently run the interpreter
-    on TPU like the explicit ``interpret=True`` research wrappers do.
+    ``cfg.decode_backend``), so it must never run the interpreter on TPU.
 
     ``return_flags=True`` additionally returns per-SEQUENCE int32 [B, 4]
     IEEE flag counts (OF, UF, NX, NV summed over heads and KV blocks;
     each live K/V element once, Q once per head row — docs/KERNELS.md)
     from the kernel's ``debug_flags`` counters.
     """
-    if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+    interpret = resolve_interpret(interpret)
     policy = get_policy(policy) if policy is not None else get_policy("tp_bf16")
     mp = policy.matmul
     if policy.mode == "native":
@@ -340,7 +347,7 @@ def decode_attention(q, k, v, *, kv_len, policy=None,
     return o[:, :group].reshape(b, hkv, group, d).reshape(b, h, 1, d)
 
 
-def dotp_ex(a, b, *, policy=None, interpret: bool = True):
+def dotp_ex(a, b, *, policy=None, interpret: Optional[bool] = None):
     """Expanding dot product of two 1D streams (paper Fig 11e)."""
     policy = get_policy(policy) if policy is not None else get_policy("tp_fp16")
     src_dt = (policy.matmul.src_fmt.native_dtype
@@ -355,5 +362,5 @@ def dotp_ex(a, b, *, policy=None, interpret: bool = True):
     a2, _ = _pad_to(a2, (br,), (0,))
     b2, _ = _pad_to(b2, (br,), (0,))
     lanes = dotp_ex_pallas(a2, b2, block_rows=br, src_dtype=src_dt,
-                           interpret=interpret)
+                           interpret=resolve_interpret(interpret))
     return jnp.sum(lanes)

@@ -132,4 +132,7 @@ def widen_with_flags(x, fmt, src_dtype):
         return y.astype(src_dtype), of, uf, nx, nv
     y = x.astype(src_dtype)
     none = jnp.zeros(x.shape, jnp.bool_)
-    return y, jnp.isinf(x), none, none, jnp.isnan(x)
+    # classify on the exact f32 widening: the TPU vector unit has no
+    # narrow-float compares (Mosaic refuses bf16 cmpf on v5e)
+    xf = x.astype(jnp.float32)
+    return y, jnp.isinf(xf), none, none, jnp.isnan(xf)

@@ -15,6 +15,10 @@ def force_dryrun_devices() -> None:
     codegen enough to break bit-exact kernel-vs-oracle comparisons.
     """
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+    # the placeholder devices are CPU devices by construction: pin the
+    # platform so a dry-run started on an accelerator host never reaches
+    # for the chip another process holds
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
 
 if __name__ == "__main__":
@@ -190,28 +194,14 @@ def lower_and_compile(arch_cfg, shape_name, mesh, policy, **kw):
                                "compile_s": round(t2 - t1, 2)}
 
 
-def _cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a dict (jax >= 0.5) or a one-element
-    list of dicts (0.4.x)."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def compiled_record(compiled, times) -> dict:
     ma = compiled.memory_analysis()
-    ca = _cost_dict(compiled)
+    ca = compiled.cost_analysis()
     txt = compiled.as_text()
     return {
         "times": times,
         "memory": {
-            # jax 0.4.x CompiledMemoryStats has no peak_memory_in_bytes;
-            # temp+args+output is the standard upper-bound proxy there
-            "peak_bytes": getattr(
-                ma, "peak_memory_in_bytes",
-                ma.temp_size_in_bytes + ma.argument_size_in_bytes
-                + ma.output_size_in_bytes),
+            "peak_bytes": ma.peak_memory_in_bytes,
             "argument_bytes": ma.argument_size_in_bytes,
             "output_bytes": ma.output_size_in_bytes,
             "temp_bytes": ma.temp_size_in_bytes,
@@ -318,7 +308,7 @@ def _measure(cfg, shape_name, mesh, policy, *, seq=None, batch=None,
         lowered, compiled, times = lower_and_compile(
             cfg, name, mesh, policy,
             loss_chunk=loss_chunk or sh["seq"])
-        ca = _cost_dict(compiled)
+        ca = compiled.cost_analysis()
         return {
             "flops": ca.get("flops", 0.0),
             "bytes": ca.get("bytes accessed", 0.0),

@@ -484,17 +484,20 @@ class ContinuousEngine:
         self.caches = init_caches(cfg, slots, max_len, model.policy,
                                   page_table=self._table,
                                   n_pages=self.n_pages)
-        # tensor-parallel placement: with a model axis > 1, pin the params
-        # (Megatron col/row rules) and the paged pools (head-sharded) to
-        # the mesh up front — the jitted burst/chunk programs then keep
-        # those shardings through every donated carry instead of
-        # rediscovering them per dispatch.  batch_axes=() because ONE
-        # engine is one data replica: its slots never batch-shard, and
-        # its block tables stay host-managed and model-replicated.
+        # placement: with a mesh, pin the params (Megatron col/row rules
+        # over the model axis) and the paged pools (head-sharded) to the
+        # mesh's devices up front — a one-device replica sub-mesh lands
+        # its whole engine on THAT device, not the default one, and the
+        # jitted burst/chunk programs keep those shardings through every
+        # donated carry instead of rediscovering them per dispatch.
+        # batch_axes=() because ONE engine is one data replica: its slots
+        # never batch-shard, and its block tables stay host-managed and
+        # model-replicated.  Params already in these shardings (e.g.
+        # ``Model.init(key, mesh=...)``) are not copied.
         self.tp = (mesh.shape["model"]
                    if mesh is not None
                    and "model" in getattr(mesh, "axis_names", ()) else 1)
-        if self.tp > 1:
+        if mesh is not None:
             from ..models.sharding import cache_specs, named, param_specs
             self.params = jax.device_put(
                 params, named(mesh, param_specs(params,
@@ -542,6 +545,11 @@ class ContinuousEngine:
         self._round_no = self._decode_rounds = 0
         self._occ_accum = self._bursts = 0
         self._key = None
+        # diagnostics: set to a dict to capture, per request id, the
+        # last-prompt-token logits its first token was sampled from (after
+        # the non-finite guard and penalties) — parity checks against
+        # ``Model.generate``; None skips the device-to-host copy
+        self.prompt_logits: Optional[Dict[int, np.ndarray]] = None
         self.reset_monitors()
 
         use_pen = self._use_pen
@@ -629,7 +637,7 @@ class ContinuousEngine:
         self.watchdog = ServeWatchdog(self.watchdog_patience)
         self.monitor = StragglerMonitor()
 
-    def _chunk_fn(self, off: int, m: int):
+    def _chunk_fn(self, off: int, m: int, capture: bool = False):
         """Jitted prefill chunk for an ``m``-slot admission wave at static
         offset ``off`` (offsets step in multiples of ``self.chunk``, waves
         are at most ``slots`` wide, so few programs ever compile; slot
@@ -638,8 +646,10 @@ class ContinuousEngine:
         into the same dispatch: the returned [m] tokens are each row's
         sample off its last live chunk position (only meaningful for a row
         whose final chunk this is), guarded against non-finite logits and
-        penalized like every other sampling site."""
-        fn = self._chunk_fns.get((off, m))
+        penalized like every other sampling site.  ``capture`` (the
+        ``prompt_logits`` diagnostic) compiles a twin that also returns
+        those [m, V] logits; the serving program never outputs them."""
+        fn = self._chunk_fns.get((off, m, capture))
         if fn is None:
             model, sample, mesh = self.model, self._sample, self.mesh
             with_table = self._with_table
@@ -659,10 +669,11 @@ class ContinuousEngine:
                 lgv, bad = sanitize(lg[:, -1])
                 if use_pen:
                     lgv = pen(lgv, counts)
-                return sample(lgv, key), bad, caches, fl
+                out = (sample(lgv, key), bad, caches, fl)
+                return out + (lgv,) if capture else out
 
             fn = self._jax.jit(chunk_step, donate_argnums=(1,))
-            self._chunk_fns[(off, m)] = fn
+            self._chunk_fns[(off, m, capture)] = fn
         return fn
 
     def _reserved_pages(self) -> int:
@@ -1332,7 +1343,9 @@ class ContinuousEngine:
                 sk = key
             cnts = (jnp.asarray(self._cnt[rows]) if self._use_pen
                     else None)
-            tok0, badp, self.caches, flp = self._chunk_fn(off, m)(
+            capture = self.prompt_logits is not None
+            tok0, badp, self.caches, flp, *lgs = self._chunk_fn(
+                off, m, capture)(
                 self.params, self.caches, self._table_device(),
                 jnp.asarray(buf), jnp.asarray(meta), cnts, sk)
             tok0, badp = np.asarray(tok0), np.asarray(badp)
@@ -1363,6 +1376,8 @@ class ContinuousEngine:
                     self.limit[b] = req.prompt_len + req.max_new - 1
                     self.done[b] = False
                     continue
+                if capture:
+                    self.prompt_logits[req.rid] = np.asarray(lgs[0][i])
                 t0 = int(tok0[i])
                 self._emitted[b] = [t0]
                 if self.journal is not None:

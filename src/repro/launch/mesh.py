@@ -3,35 +3,21 @@
 ``make_production_mesh`` / ``make_serving_mesh`` are FUNCTIONS (not module
 constants) so importing this module never touches jax device state —
 mandatory because the dry-run must set XLA_FLAGS before any jax
-initialization.
-
-Version gates (both paths unit-tested by monkeypatching, not just the
-installed version's branch):
-  * ``jax.make_mesh`` (new in 0.4.35ish) vs. hand-reshaping
-    ``jax.devices()`` into ``jax.sharding.Mesh`` — ``_mk_mesh``.
-  * ``jax.sharding.AxisType`` (jax >= 0.5 explicit-sharding types) —
-    probed with ``hasattr``; 0.4.x meshes take no ``axis_types``.
+initialization.  Every mesh is built with ``AxisType.Auto`` axes (GSPMD
+propagation plus explicit ``shard_map`` regions — the sharding model the
+model code is written against).
 """
 from __future__ import annotations
-
-import math
 
 import jax
 import numpy as np
 
 
-def _mk_mesh(shape, axes, **kw):
-    """Build a Mesh over the first ``prod(shape)`` devices, via
-    ``jax.make_mesh`` when this jax has it, else the classic
-    ``jax.sharding.Mesh(np.reshape(devices), axes)`` construction."""
-    if hasattr(jax, "make_mesh"):
-        return jax.make_mesh(shape, axes, **kw)
-    n = math.prod(shape)
-    devs = jax.devices()
-    if len(devs) < n:
-        raise ValueError(f"mesh {shape} needs {n} devices, "
-                         f"have {len(devs)}")
-    return jax.sharding.Mesh(np.array(devs[:n]).reshape(shape), axes)
+def _mk_mesh(shape, axes):
+    """``jax.make_mesh`` over the first ``prod(shape)`` devices with Auto
+    axes (``make_mesh`` itself defaults to Explicit)."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -40,10 +26,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     a second data-parallel axis crossing the DCN/ICI boundary."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    kw = {}
-    if hasattr(jax.sharding, "AxisType"):   # jax >= 0.5 (Auto is the
-        kw["axis_types"] = (jax.sharding.AxisType.Auto,) * len(axes)
-    return _mk_mesh(shape, axes, **kw)      # 0.4.x default)
+    return _mk_mesh(shape, axes)
 
 
 def make_serving_mesh(dp: int, tp: int):

@@ -352,6 +352,9 @@ def main(argv=None):
         os.environ["XLA_FLAGS"] = \
             (flag + " " + os.environ.get("XLA_FLAGS", "")).strip()
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -363,7 +366,6 @@ def main(argv=None):
                            prefill_backend=args.prefill_backend)
     if args.paged or args.continuous:
         model = model.with_cfg(paged_kv=True, page_size=args.page_size)
-    params = model.init(jax.random.key(0))
 
     mesh = rmesh = None
     dp = 1
@@ -375,6 +377,9 @@ def main(argv=None):
         print(f"serving mesh: {dp} data-parallel replica(s) x {tp}-way "
               f"tensor parallel over {dp * tp} of {jax.device_count()} "
               f"devices")
+    # born in replica 0's shardings: no device ever holds a whole-model
+    # copy beside its shard (the other replicas copy from these)
+    params = model.init(jax.random.key(0), mesh=rmesh)
 
     if args.continuous:
         import dataclasses as _dc

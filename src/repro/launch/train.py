@@ -27,6 +27,9 @@ def main(argv=None):
     ap.add_argument("--full", dest="reduced", action="store_false")
     args = ap.parse_args(argv)
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     if args.mesh != "none":
         from .mesh import make_production_mesh
         mesh = make_production_mesh(multi_pod=args.mesh == "pod2")

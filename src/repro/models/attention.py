@@ -140,12 +140,19 @@ def gqa_params(key, d_model, n_heads, n_kv_heads, head_dim, dtype,
 
 def _use_pallas_prefill(backend: str, q_offset=0) -> bool:
     """Route prefill/train attention through the pruned-grid Pallas kernel?
-    ``q_offset`` must be a concrete int (it is a static kernel arg that
-    shapes the block schedule); a traced offset falls back to dense."""
+    ``q_offset`` must then be a concrete int (a static kernel arg that
+    shapes the block schedule): a traced offset raises instead of quietly
+    serving the dense path under a Pallas backend."""
     if backend == "dense":
         return False
     from ..kernels.ops import resolve_backend
-    return resolve_backend(backend) == "pallas" and isinstance(q_offset, int)
+    if resolve_backend(backend) != "pallas":
+        return False
+    if not isinstance(q_offset, int):
+        raise ValueError(
+            f"Pallas prefill needs a static int q_offset (it shapes the "
+            f"kernel's block schedule), got {type(q_offset).__name__}")
+    return True
 
 
 def _flash_attend(q, k, v, policy, *, causal, window, cap, q_offset=0,
@@ -307,11 +314,10 @@ def _headshard_call(mesh, fn, q, head_ops=(), rep_ops=(),
     Every traced operand must be passed explicitly (shard_map closures
     must not capture tracers); ``fn`` may capture only static
     configuration (policy, window, softcap, static q_offset...)."""
-    from ..core.compat import shard_map_compat
     hs = P(None, axis, None, None)
     in_specs = (hs,) * (1 + len(head_ops)) + (P(),) * len(rep_ops)
-    f = shard_map_compat(fn, mesh=mesh, in_specs=in_specs, out_specs=hs,
-                         axis_names=set(mesh.axis_names))
+    f = jax.shard_map(fn, mesh=mesh, in_specs=in_specs, out_specs=hs,
+                      axis_names=set(mesh.axis_names), check_vma=False)
     return f(q, *head_ops, *rep_ops)
 
 
@@ -329,16 +335,14 @@ def _row_parallel_wo(mesh, out, wo, policy, axis: str = "model"):
     sum (exactly where the single-device ``tp_einsum`` applies it) — a
     per-shard snap would quantize the partials themselves and drift by a
     whole output-format ulp instead of fp32 reduction-order noise."""
-    from ..core.compat import shard_map_compat
-
     def body(o, w):
         return jax.lax.psum(
             tp.tp_einsum("bse,ed->bsd", o, w, policy, out_fmt="fp32"), axis)
 
-    f = shard_map_compat(body, mesh=mesh,
-                         in_specs=(P(None, None, axis), P(axis, None)),
-                         out_specs=P(),
-                         axis_names=set(mesh.axis_names))
+    f = jax.shard_map(body, mesh=mesh,
+                      in_specs=(P(None, None, axis), P(axis, None)),
+                      out_specs=P(), axis_names=set(mesh.axis_names),
+                      check_vma=False)
     r = f(out, wo)
     pol = tp.get_policy(policy)
     mp = pol.matmul

@@ -191,7 +191,6 @@ def moe_block(x, params, cfg: MoEConfig, policy, *, mesh=None,
     if mesh is not None and ep_axis in mesh.axis_names and \
             mesh.shape[ep_axis] > 1:
         ep = mesh.shape[ep_axis]
-        from ..core.compat import shard_map_compat as shard_map
         espec = P(ep_axis)
         pspec = {"router": P(), "w_gate": espec, "w_up": espec,
                  "w_down": espec}
@@ -201,7 +200,7 @@ def moe_block(x, params, cfg: MoEConfig, policy, *, mesh=None,
                                 ep_axis=ep_axis, ep_size=ep)
             return yb, auxb.reshape((1,) * max(len(ba), 1))
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             body, mesh=mesh,
             in_specs=(P(ba if ba else None), pspec),
             out_specs=(P(ba), P(*ba) if ba else P()),

@@ -496,7 +496,22 @@ class Model:
             self, cfg=dataclasses.replace(self.cfg, **overrides))
 
     # -- init ------------------------------------------------------------
-    def init(self, key) -> dict:
+    def init(self, key, mesh=None) -> dict:
+        """Random parameters from ``key``, built by ONE jitted program so
+        only its outputs are ever materialized (no per-layer copies live
+        beside the stacked ones).  With ``mesh``, every parameter is
+        created directly in its ``models.sharding.param_specs`` layout over
+        the mesh's ``model`` axis — no device ever holds a whole-model
+        copy of a sharded tensor."""
+        shardings = None
+        if mesh is not None:
+            from .sharding import named, param_specs
+            shapes = jax.eval_shape(self._init, key)
+            shardings = named(mesh, param_specs(
+                shapes, model_size=dict(mesh.shape).get("model", 1)))
+        return jax.jit(self._init, out_shardings=shardings)(key)
+
+    def _init(self, key) -> dict:
         cfg = self.cfg
         dtype = param_dtype(self.policy)
         n_keys = len(cfg.prefix) + len(cfg.suffix) + cfg.repeats * len(
@@ -517,12 +532,13 @@ class Model:
             init_layer(ks.pop(), s, cfg, dtype) for s in cfg.prefix)
         params["suffix"] = tuple(
             init_layer(ks.pop(), s, cfg, dtype) for s in cfg.suffix)
-        # stacked pattern params [R, ...]
-        groups = []
-        for _ in range(cfg.repeats):
-            groups.append(tuple(init_layer(ks.pop(), s, cfg, dtype)
-                                for s in cfg.pattern))
-        params["pattern"] = jax.tree.map(lambda *xs: jnp.stack(xs), *groups)
+        # stacked pattern params [R, ...]: one vmapped init over the
+        # per-group keys writes the stacked arrays directly
+        gkeys = jnp.stack([jnp.stack([ks.pop() for _ in cfg.pattern])
+                           for _ in range(cfg.repeats)])
+        params["pattern"] = jax.vmap(lambda kk: tuple(
+            init_layer(kk[j], s, cfg, dtype)
+            for j, s in enumerate(cfg.pattern)))(gkeys)
         if cfg.shared_block is not None:
             params["shared"] = init_shared_block(ks.pop(), cfg, dtype)
         if cfg.encoder is not None:
@@ -532,7 +548,10 @@ class Model:
     # -- embedding / unembedding ------------------------------------------
     def embed(self, params, tokens, frontend_embeds=None, *, pos_offset=0):
         cfg = self.cfg
-        x = params["embed"][tokens]
+        # activations start in the policy's storage dtype (a no-op for
+        # params made by ``init``; widens narrower stored weights exactly)
+        x = params["embed"][tokens].astype(
+            param_dtype(get_policy(self.policy)))
         if cfg.emb_scale:
             x = (x.astype(F32) * cfg.emb_scale).astype(x.dtype)
         if cfg.frontend == "patch" and frontend_embeds is not None:

@@ -28,7 +28,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..core.compat import shard_map_compat
 from ..core.policy import PrecisionPolicy, get_policy
 from ..models import sharding as shd
 from ..models.layers import set_batch_axes
@@ -128,7 +127,7 @@ def make_train_step(model: Model, opt_cfg: OptConfig, mesh, *,
         kq, ksr = jax.random.split(key)
         batch_specs = {k: P(dpa, *([None] * (v.ndim - 1)))
                        for k, v in batch.items()}
-        loss, grads, ef = shard_map_compat(
+        loss, grads, ef = jax.shard_map(
             local_grad_body, mesh=mesh,
             in_specs=(P(), batch_specs, ef_spec, P()),
             out_specs=(P(), P(), ef_spec),

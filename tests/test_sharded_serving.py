@@ -15,8 +15,7 @@ parity claims they pin:
   * the continuous engine and its data-parallel replication emit
     token-identical streams with and without a mesh.
 
-The 1-device satellite tests (compat/version-gate branches, divisibility
-fallback, per-replica allocator isolation, paged cache specs, queue
+The 1-device satellite tests (mesh axis types, divisibility fallback, per-replica allocator isolation, paged cache specs, queue
 partitioning) always run.
 """
 import types
@@ -28,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.core.compat import shard_map_compat
 from repro.launch import mesh as meshmod
 from repro.launch.engine import ReplicatedEngine, Request
 from repro.models.attention import KVCache, gqa_attention, gqa_params
@@ -235,66 +233,16 @@ def test_moe_ep_on_model_only_mesh():
 
 
 # ---------------------------------------------------------------------------
-# satellite 1: version-gate shims — BOTH branches, monkeypatched
+# satellite 1: meshes are built with Auto axes
 # ---------------------------------------------------------------------------
-def test_shard_map_compat_new_api_branch(monkeypatch):
-    calls = {}
-
-    def fake(f, *, mesh, in_specs, out_specs, check_vma, **kw):
-        calls.update(kw, mesh=mesh, check_vma=check_vma)
-        return "new-api"
-
-    monkeypatch.setattr(jax, "shard_map", fake, raising=False)
-    r = shard_map_compat(lambda x: x, mesh="M", in_specs=(P(),),
-                         out_specs=P(), axis_names={"model"})
-    assert r == "new-api"
-    assert calls["mesh"] == "M" and calls["axis_names"] == {"model"}
-    assert calls["check_vma"] is False
-
-
-def test_shard_map_compat_legacy_branch(monkeypatch):
-    # force the 0.4.x path even on a newer jax, and prove it RUNS
-    monkeypatch.delattr(jax, "shard_map", raising=False)
-    mesh = tp_mesh(1)
-    f = shard_map_compat(lambda x: x * 2, mesh=mesh, in_specs=(P(),),
-                         out_specs=P(), axis_names=set(mesh.axis_names))
-    np.testing.assert_array_equal(np.asarray(f(jnp.arange(4))),
-                                  np.arange(4) * 2)
-
-
-def test_mk_mesh_new_api_branch(monkeypatch):
-    calls = {}
-
-    def fake(shape, axes, **kw):
-        calls.update(shape=shape, axes=axes, **kw)
-        return "made"
-
-    monkeypatch.setattr(jax, "make_mesh", fake, raising=False)
-    assert meshmod._mk_mesh((1, 1), ("data", "model")) == "made"
-    assert calls["shape"] == (1, 1) and calls["axes"] == ("data", "model")
-
-
-def test_mk_mesh_classic_branch(monkeypatch):
-    monkeypatch.delattr(jax, "make_mesh", raising=False)
-    m = meshmod._mk_mesh((1, 1), ("data", "model"))
-    assert m.axis_names == ("data", "model") and m.devices.size == 1
-    with pytest.raises(ValueError, match="needs"):
-        meshmod._mk_mesh((4096,), ("model",))
-
-
 def test_production_mesh_axis_type_probe(monkeypatch):
     seen = {}
     monkeypatch.setattr(jax, "make_mesh",
-                        lambda shape, axes, **kw: seen.update(kw) or "m",
-                        raising=False)
-    fake_at = types.SimpleNamespace(Auto="AUTO")
-    monkeypatch.setattr(jax.sharding, "AxisType", fake_at, raising=False)
+                        lambda shape, axes, **kw: seen.update(kw) or "m")
     assert meshmod.make_production_mesh() == "m"
-    assert seen == {"axis_types": ("AUTO", "AUTO")}
-    seen.clear()
-    monkeypatch.delattr(jax.sharding, "AxisType", raising=False)
-    assert meshmod.make_production_mesh() == "m"
-    assert seen == {}
+    assert seen == {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
+    assert meshmod.make_serving_mesh(1, 1) == "m"
+    assert seen == {"axis_types": (jax.sharding.AxisType.Auto,) * 2}
 
 
 def test_serving_mesh_validation():

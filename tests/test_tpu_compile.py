@@ -1,0 +1,165 @@
+"""Compile-only tests: the serving-path Pallas kernels at real widths,
+compiled by the TPU compiler for a described (not attached) v5e chip.
+
+Interpret-mode tests prove the kernels' numerics on the CPU; they cannot
+show what Mosaic refuses (unaligned block shapes, VMEM over-use) or that a
+program fits the device.  These tests lower and compile each kernel for
+one chip of a ``v5e:2x2`` topology and check that the compiled program
+really contains the kernel (``tpu_custom_call``).  Nothing runs: there is
+no result and no timing here.
+
+The topology is described inside a module-scoped fixture, never at import:
+only one process may load the TPU library at a time, and every pytest
+worker imports every test file.  The fixture skips where no topology can
+be described.  JAX's persistent compilation cache is switched off for the
+duration — a program compiled for a described chip is written to the cache
+but cannot be read back without one.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core.policy import get_policy
+from repro.kernels import ops as kops
+from repro.kernels.decode_attention import decode_attention_pallas
+from repro.kernels.flash_attention import flash_attention_pallas
+from repro.models.attention import kv_store_dtype
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        t = topologies.get_topology_desc(platform="tpu",
+                                         topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler, or the library is held
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield t
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(one_chip, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+
+def _compile(fn, *specs):
+    """Compile ``fn`` for the described chip; returns the compiled text
+    after checking the kernel made it into the program."""
+    compiled = jax.jit(fn).lower(*specs).compile()
+    txt = compiled.as_text()
+    assert "tpu_custom_call" in txt
+    return txt
+
+
+# (name, H, Hkv, Dh, policy, window, softcap): qwen3-moe-30b-a3b and
+# gemma2-9b attention widths (configs/*.py)
+DECODE_CASES = [
+    ("qwen3-bf16", 32, 4, 128, "tp_bf16", None, None),
+    ("qwen3-fp8", 32, 4, 128, "tp_bf16_kv8", None, None),
+    ("gemma2-window-softcap", 16, 8, 256, "tp_bf16", 4096, 50.0),
+]
+
+SLOTS, PAGE, MAX_PAGES = 4, 128, 9       # 4 serving slots, 1152-token rows
+
+
+@pytest.mark.parametrize("case", DECODE_CASES, ids=[c[0] for c in DECODE_CASES])
+def test_paged_decode_compiles(one_chip, case):
+    _, h, hkv, dh, pol, window, cap = case
+    policy = get_policy(pol)
+    n_pages = SLOTS * MAX_PAGES + 1
+    pool_dt = kv_store_dtype(policy)
+    fn = lambda q, kp, vp, bt, kvl: kops.decode_attention(
+        q, kp, vp, kv_len=kvl, block_table=bt, policy=policy,
+        window=window, softcap=cap, interpret=False)
+    _compile(fn, _spec(one_chip, (SLOTS, h, 1, dh), jnp.bfloat16),
+             _spec(one_chip, (n_pages, hkv, PAGE, dh), pool_dt),
+             _spec(one_chip, (n_pages, hkv, PAGE, dh), pool_dt),
+             _spec(one_chip, (SLOTS, MAX_PAGES), jnp.int32),
+             _spec(one_chip, (SLOTS,), jnp.int32))
+
+
+def test_paged_flash_prefill_chunk_compiles(one_chip):
+    # one 128-token prefill chunk of one slot at offset 256, reading the
+    # row's earlier chunks back through the page pool (qwen3 widths)
+    n_pages = SLOTS * MAX_PAGES + 1
+    fn = lambda q, kp, vp, bt, kvl: kops.flash_attention(
+        q, kp, vp, kv_len=kvl, block_table=bt, policy="tp_bf16",
+        q_offset=256, interpret=False)
+    _compile(fn, _spec(one_chip, (1, 32, 128, 128), jnp.bfloat16),
+             _spec(one_chip, (n_pages, 4, PAGE, 128), jnp.bfloat16),
+             _spec(one_chip, (n_pages, 4, PAGE, 128), jnp.bfloat16),
+             _spec(one_chip, (1, MAX_PAGES), jnp.int32),
+             _spec(one_chip, (1,), jnp.int32))
+
+
+def test_contiguous_flash_prefill_compiles(one_chip):
+    fn = lambda q, k, v, kvl: kops.flash_attention(
+        q, k, v, kv_len=kvl, policy="tp_bf16", interpret=False)
+    _compile(fn, _spec(one_chip, (1, 32, 1024, 128), jnp.bfloat16),
+             _spec(one_chip, (1, 4, 1024, 128), jnp.bfloat16),
+             _spec(one_chip, (1, 4, 1024, 128), jnp.bfloat16),
+             _spec(one_chip, (1,), jnp.int32))
+
+
+@pytest.mark.parametrize("debug", ["visits", "flags"])
+def test_decode_debug_outputs_compile(one_chip, debug):
+    # paged qwen3 decode with the telemetry outputs on
+    bh, nk = SLOTS * 4, MAX_PAGES
+    n_pages = (SLOTS * MAX_PAGES + 1) * 4
+    fn = lambda q, kp, vp, kvl, bt: decode_attention_pallas(
+        q, kp, vp, kvl, bt, bk=PAGE, scale=128 ** -0.5,
+        src_dtype=jnp.bfloat16, interpret=False,
+        debug_visits=debug == "visits", debug_flags=debug == "flags")
+    txt = _compile(fn, _spec(one_chip, (bh, 8, 128), jnp.bfloat16),
+                   _spec(one_chip, (n_pages, PAGE, 128), jnp.bfloat16),
+                   _spec(one_chip, (n_pages, PAGE, 128), jnp.bfloat16),
+                   _spec(one_chip, (bh, 1), jnp.int32),
+                   _spec(one_chip, (bh, nk), jnp.int32))
+    assert txt
+
+
+@pytest.mark.parametrize("debug", ["visits", "flags"])
+def test_flash_debug_outputs_compile(one_chip, debug):
+    fn = lambda q, k, v, kvl: flash_attention_pallas(
+        q, k, v, kvl, group=8, bq=128, bk=128, scale=128 ** -0.5,
+        src_dtype=jnp.bfloat16, interpret=False,
+        debug_visits=debug == "visits", debug_flags=debug == "flags")
+    _compile(fn, _spec(one_chip, (32, 512, 128), jnp.bfloat16),
+             _spec(one_chip, (4, 512, 128), jnp.bfloat16),
+             _spec(one_chip, (4, 512, 128), jnp.bfloat16),
+             _spec(one_chip, (32,), jnp.int32))
+
+
+def test_tp_matmul_compiles(one_chip):
+    fn = lambda a, b: kops.tp_matmul(a, b, policy="tp_bf16",
+                                     interpret=False)
+    _compile(fn, _spec(one_chip, (256, 2048), jnp.bfloat16),
+             _spec(one_chip, (2048, 768), jnp.bfloat16))
+
+
+def test_tp_quantize_fp8_compiles(one_chip):
+    fn = lambda x: kops.tp_quantize(x, fmt="fp8", interpret=False)
+    _compile(fn, _spec(one_chip, (512, 1024), jnp.float32))
+
+
+def test_interpret_resolves_from_platform():
+    # None follows the platform; an explicit bool pins the mode
+    assert kops.resolve_interpret(None) == (jax.default_backend() == "cpu")
+    assert kops.resolve_interpret(False) is False
+    assert kops.resolve_interpret(True) is True
+    assert np.isfinite(float(kops.dotp_ex(jnp.ones(4), jnp.ones(4))))

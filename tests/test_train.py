@@ -109,8 +109,7 @@ def test_compress_sync_error_feedback_converges():
         def body(g, ef):
             return compress_sync_local(g, ef, axes=("data",), fmt="fp8",
                                        key=jax.random.key(i), n_replicas=1)
-        from repro.core.compat import shard_map_compat
-        return jax.jit(shard_map_compat(
+        return jax.jit(jax.shard_map(
             body, mesh=mesh,
             in_specs=(jax.sharding.PartitionSpec(),) * 2,
             out_specs=(jax.sharding.PartitionSpec(),) * 2,
