@@ -59,7 +59,7 @@ def main():
                                           np.asarray(y, np.float32))
         print("final weights BIT-IDENTICAL to the uninterrupted run  [OK]")
         if loop.monitor.flagged:
-            print("stragglers flagged:", loop.monitor.flagged)
+            print("stragglers flagged:", list(loop.monitor.flagged))
     finally:
         shutil.rmtree(tmp_a, ignore_errors=True)
         shutil.rmtree(tmp_b, ignore_errors=True)

@@ -124,6 +124,7 @@ from ..core.policy import EscalationPolicy
 from ..train.fault import (EngineStuckError, PoisonedLogitsError,
                            ReplicaFaultPlan, ReplicaLostError,
                            ServeFaultPlan, ServeWatchdog, StragglerMonitor)
+from .spans import Spans
 
 
 def _crc_blobs(blobs: list) -> list:
@@ -218,7 +219,12 @@ class _QEntry:
     counters and (after a preemption) the resume state.  ``esc_level`` /
     ``esc_pressure`` persist the request's escalation rung and accumulated
     OF/UF flag pressure across preemptions (the rung is a property of the
-    REQUEST, not the slot it happens to occupy)."""
+    REQUEST, not the slot it happens to occupy).  ``enqueued_ns`` /
+    ``admitted_ns`` / ``first_ns`` (``perf_counter_ns``) are when the
+    request first entered a queue (``start()`` or ``adopt()``), was first
+    admitted, and had its first token on the host: the ends of its
+    ``engine.queued``, ``engine.first_token`` and ``engine.finish``
+    marks."""
     req: Request
     not_before: int
     sheds: int = 0
@@ -228,6 +234,9 @@ class _QEntry:
     esc_level: int = 0
     esc_pressure: tuple = (0, 0)
     esc_refused: bool = False
+    enqueued_ns: Optional[int] = None
+    admitted_ns: Optional[int] = None
+    first_ns: Optional[int] = None
 
 
 def _finished_from_record(rec: dict) -> Finished:
@@ -550,6 +559,9 @@ class ContinuousEngine:
         # the non-finite guard and penalties) — parity checks against
         # ``Model.generate``; None skips the device-to-host copy
         self.prompt_logits: Optional[Dict[int, np.ndarray]] = None
+        # host spans of every step() phase and per-request marks
+        # (launch/spans.py), reset by start() with the counters
+        self.spans = Spans()
         self.reset_monitors()
 
         use_pen = self._use_pen
@@ -655,6 +667,7 @@ class ContinuousEngine:
             with_table = self._with_table
             sanitize, pen, use_pen = self._sanitize, self._pen, self._use_pen
             esc_fmts, jnp = self._esc_fmts, self._jnp
+            named_scope = self._jax.named_scope
 
             def chunk_step(params, caches, table, t, meta, counts, key):
                 caches = with_table(caches, table)
@@ -666,10 +679,12 @@ class ContinuousEngine:
                 lg, caches = r[0], r[1]
                 fl = (r[2] if esc_fmts is not None
                       else jnp.zeros((t.shape[0], 2), jnp.int32))
-                lgv, bad = sanitize(lg[:, -1])
-                if use_pen:
-                    lgv = pen(lgv, counts)
-                out = (sample(lgv, key), bad, caches, fl)
+                with named_scope("sample"):
+                    lgv, bad = sanitize(lg[:, -1])
+                    if use_pen:
+                        lgv = pen(lgv, counts)
+                    tok = sample(lgv, key)
+                out = (tok, bad, caches, fl)
                 return out + (lgv,) if capture else out
 
             fn = self._jax.jit(chunk_step, donate_argnums=(1,))
@@ -931,6 +946,10 @@ class ContinuousEngine:
         swap and reingest page needs coincide) and reproduces the
         un-preempted run bit for bit."""
         req = e.req
+        if e.admitted_ns is None:
+            e.admitted_ns = time.perf_counter_ns()
+            self.spans.mark("engine.queued", e.enqueued_ns, e.admitted_ns,
+                            rid=req.rid)
         self._table[b, :len(pages)] = pages
         self._table_dirty = True
         self._owned[b] = pages
@@ -1050,6 +1069,8 @@ class ContinuousEngine:
         the robustness trail land on the Finished record here."""
         req = self._req[b]
         e = self._entry[b]
+        self.spans.mark("engine.finish", e.first_ns or e.admitted_ns,
+                        time.perf_counter_ns(), rid=req.rid)
         fin = Finished(
             rid=req.rid, prompt_len=req.prompt_len,
             tokens=list(self._emitted[b]),
@@ -1168,6 +1189,7 @@ class ContinuousEngine:
             plan.reset()
         self._held, self._release_at = [], None
         self.reset_monitors()
+        self.spans.reset()
         self._counters = {k: 0 for k in (
             "preemptions", "preempt_swap", "preempt_reingest",
             "preempt_restart", "resumed", "degraded", "swap_out_bytes",
@@ -1215,6 +1237,9 @@ class ContinuousEngine:
                     jr.append("replay", rid=r.rid,
                               replica=self.replica_id, from_tok=len(em))
             pend.append(e)
+        now = time.perf_counter_ns()
+        for e in pend:
+            e.enqueued_ns = now
         self._pending = pend
 
     def has_work(self) -> bool:
@@ -1265,11 +1290,14 @@ class ContinuousEngine:
         any preempted-and-requeued local request."""
         from ..models.paged import check_blob_tag
         n = 0
+        now = time.perf_counter_ns()
         for e in entries:
             if e.resume is not None and e.resume.blobs is not None:
                 check_blob_tag(e.resume.tag, dtype=self._pool_dtype,
                                page=self.page)
             e.not_before = self._round_no
+            if e.enqueued_ns is None:
+                e.enqueued_ns = now
             self._pending.append(e)
             self._counters["migrated_in"] += 1
             if self.journal is not None:
@@ -1287,13 +1315,25 @@ class ContinuousEngine:
         chunks -> at most one decode burst -> finish/escalate accounting.
         Returns ``has_work()`` — False once drained.  Raises
         ``ReplicaLostError`` at the burst dispatch when this replica's
-        ``replica_fault`` kill is due (the simulated device loss)."""
+        ``replica_fault`` kill is due (the simulated device loss).
+
+        Each call is one ``engine.step`` span; its phases are child spans
+        (``engine.admission``, ``engine.prefill`` with its
+        ``engine.prefill.readback``, ``engine.burst`` with its
+        ``prepare`` / ``dispatch`` / ``readback``,
+        ``engine.burst.bookkeeping``, ``engine.escalate``)."""
         if not self.has_work():
             return False
+        with self.spans.span("engine.step"):
+            self._step()
+        return self.has_work()
+
+    def _step(self) -> None:
         jnp, jax = self._jnp, self._jax
         plan = self.fault_plan
         counters = self._counters
         watchdog, monitor = self.watchdog, self.monitor
+        spans = self.spans
         key = self._key
         progress = 0
 
@@ -1315,8 +1355,13 @@ class ContinuousEngine:
                           until=self._release_at)
 
         # -- admission: place queue entries (preempt/degrade/shed) --------
-        admitted, self.caches = self._admission(
-            self._round_no, self.caches, counters)
+        preempted = counters["preemptions"]
+        with spans.span("engine.admission",
+                        pending=len(self._pending)) as admission:
+            admitted, self.caches = self._admission(
+                self._round_no, self.caches, counters)
+            admission.set(admitted=admitted,
+                          preempted=counters["preemptions"] - preempted)
         progress += admitted
 
         # -- one prefill chunk per admitting slot, same-offset slots
@@ -1327,75 +1372,84 @@ class ContinuousEngine:
         for b in prefilling:
             waves.setdefault(int(self._prog[b]), []).append(b)
         for off, rows in sorted(waves.items()):
-            m = len(rows)
-            buf = np.zeros((m, self.chunk), np.int32)
-            meta = np.zeros((3, m), np.int32)   # rows/chunk lens/levels
-            meta[0] = rows
-            for i, b in enumerate(rows):
-                piece = self._ingest[b][off:off + self.chunk]
-                buf[i, :len(piece)] = piece
-                meta[1, i] = len(piece)
-                meta[2, i] = self.kv_levels[b]
-            if self.temperature > 0.0:
-                key, sk = jax.random.split(key)
-                self._key = key
-            else:
-                sk = key
-            cnts = (jnp.asarray(self._cnt[rows]) if self._use_pen
-                    else None)
-            capture = self.prompt_logits is not None
-            tok0, badp, self.caches, flp, *lgs = self._chunk_fn(
-                off, m, capture)(
-                self.params, self.caches, self._table_device(),
-                jnp.asarray(buf), jnp.asarray(meta), cnts, sk)
-            tok0, badp = np.asarray(tok0), np.asarray(badp)
-            if self.escalate is not None:
-                # prefill write flags feed the same per-slot pressure
-                self.flag_pressure[rows] += np.asarray(flp, np.int64)
-            progress += 1
-            for i, b in enumerate(rows):
-                req = self._req[b]
-                self._prog[b] += int(meta[1, i])
-                if int(self._prog[b]) != len(self._ingest[b]):
-                    continue
-                if badp[i]:
-                    if plan is not None and plan.mask_poison:
-                        counters["nonfinite_prefill"] += 1
-                    else:
-                        raise PoisonedLogitsError(
-                            f"non-finite prefill logits for request "
-                            f"{req.rid} (slot {b}, round "
-                            f"{self._round_no})")
-                if self._resume_tok[b] is not None:
-                    # reingest resume: the re-fed tokens only rebuild
-                    # K/V; generation continues from the last emitted
-                    # token exactly where the un-preempted run was
-                    self.tok[b, 0] = self._resume_tok[b]
-                    self._resume_tok[b] = None
-                    self.pos[b] = self.lens[b] = len(self._ingest[b])
-                    self.limit[b] = req.prompt_len + req.max_new - 1
-                    self.done[b] = False
-                    continue
-                if capture:
-                    self.prompt_logits[req.rid] = np.asarray(lgs[0][i])
-                t0 = int(tok0[i])
-                self._emitted[b] = [t0]
-                if self.journal is not None:
-                    self.journal.append("tokens", rid=req.rid,
-                                        replica=self.replica_id,
-                                        toks=[t0])
-                if self._use_pen:
-                    self._cnt[b, t0 % self._cnt.shape[1]] += 1
-                hit_stop = (self.stop_token is not None
-                            and t0 == self.stop_token)
-                if hit_stop or req.max_new == 1:
-                    self._finish(b, self._round_no, self._results)
-                    progress += 1
+            with spans.span("engine.prefill", offset=off,
+                            rows=len(rows)) as prefill:
+                m = len(rows)
+                buf = np.zeros((m, self.chunk), np.int32)
+                meta = np.zeros((3, m), np.int32)   # rows/chunk lens/levels
+                meta[0] = rows
+                for i, b in enumerate(rows):
+                    piece = self._ingest[b][off:off + self.chunk]
+                    buf[i, :len(piece)] = piece
+                    meta[1, i] = len(piece)
+                    meta[2, i] = self.kv_levels[b]
+                prefill.set(tokens=int(meta[1].sum()))
+                if self.temperature > 0.0:
+                    key, sk = jax.random.split(key)
+                    self._key = key
                 else:
-                    self.tok[b, 0] = t0
-                    self.pos[b] = self.lens[b] = req.prompt_len
-                    self.limit[b] = req.prompt_len + req.max_new - 1
-                    self.done[b] = False
+                    sk = key
+                cnts = (jnp.asarray(self._cnt[rows]) if self._use_pen
+                        else None)
+                capture = self.prompt_logits is not None
+                tok0, badp, self.caches, flp, *lgs = self._chunk_fn(
+                    off, m, capture)(
+                    self.params, self.caches, self._table_device(),
+                    jnp.asarray(buf), jnp.asarray(meta), cnts, sk)
+                with spans.span("engine.prefill.readback"):
+                    tok0, badp = np.asarray(tok0), np.asarray(badp)
+                if self.escalate is not None:
+                    # prefill write flags feed the same per-slot pressure
+                    self.flag_pressure[rows] += np.asarray(flp, np.int64)
+                progress += 1
+                for i, b in enumerate(rows):
+                    req = self._req[b]
+                    self._prog[b] += int(meta[1, i])
+                    if int(self._prog[b]) != len(self._ingest[b]):
+                        continue
+                    if badp[i]:
+                        if plan is not None and plan.mask_poison:
+                            counters["nonfinite_prefill"] += 1
+                        else:
+                            raise PoisonedLogitsError(
+                                f"non-finite prefill logits for request "
+                                f"{req.rid} (slot {b}, round "
+                                f"{self._round_no})")
+                    if self._resume_tok[b] is not None:
+                        # reingest resume: the re-fed tokens only rebuild
+                        # K/V; generation continues from the last emitted
+                        # token exactly where the un-preempted run was
+                        self.tok[b, 0] = self._resume_tok[b]
+                        self._resume_tok[b] = None
+                        self.pos[b] = self.lens[b] = len(self._ingest[b])
+                        self.limit[b] = req.prompt_len + req.max_new - 1
+                        self.done[b] = False
+                        continue
+                    if capture:
+                        self.prompt_logits[req.rid] = np.asarray(lgs[0][i])
+                    t0 = int(tok0[i])
+                    e = self._entry[b]
+                    if e.first_ns is None:
+                        e.first_ns = time.perf_counter_ns()
+                        spans.mark("engine.first_token", e.admitted_ns,
+                                   e.first_ns, rid=req.rid)
+                    self._emitted[b] = [t0]
+                    if self.journal is not None:
+                        self.journal.append("tokens", rid=req.rid,
+                                            replica=self.replica_id,
+                                            toks=[t0])
+                    if self._use_pen:
+                        self._cnt[b, t0 % self._cnt.shape[1]] += 1
+                    hit_stop = (self.stop_token is not None
+                                and t0 == self.stop_token)
+                    if hit_stop or req.max_new == 1:
+                        self._finish(b, self._round_no, self._results)
+                        progress += 1
+                    else:
+                        self.tok[b, 0] = t0
+                        self.pos[b] = self.lens[b] = req.prompt_len
+                        self.limit[b] = req.prompt_len + req.max_new - 1
+                        self.done[b] = False
 
         # -- decode burst over every slot ---------------------------------
         active = [b for b in range(self.slots) if not self.done[b]]
@@ -1475,107 +1529,115 @@ class ContinuousEngine:
                                        self._round_no + int(n_max))
                 if o is not None:
                     ovf_rel = o - self._round_no
-            t_start = time.perf_counter()
-            if plan is not None:
-                stall = plan.take_slow(self._round_no)
-                if stall > 0.0:
-                    counters["faults_slow"] += 1
-                    plan.note("slow", round=self._round_no,
-                              seconds=stall)
-                    time.sleep(stall)
-            state = np.zeros((11 if self.spec_k else 10, self.slots),
-                             np.int32)
-            state[0, :] = self.tok[:, 0]
-            state[1], state[2], state[3] = self.pos, self.lens, self.limit
-            state[4] = self.done
-            state[5, 0], state[6, 0] = n_max, wave
-            state[7, 0] = poison_rel
-            state[8] = self.kv_levels
-            state[9, 0] = ovf_rel
-            if self.spec_k:
-                state[10] = self._spec_rows
-            cnts = jnp.asarray(self._cnt) if self._use_pen else None
-            res = self._burst(self.params, self.caches,
-                              self._table_device(),
-                              jnp.asarray(state), cnts, key)
-            out, n, state_d, self.caches, key2, bad_d, fl_d = res[:7]
-            n = int(n)                    # blocks on the burst
-            new_state = np.array(state_d)
-            if self.spec_k:
-                # packed layout: row b's accepted tokens fill
-                # out[b, :lens-growth]; download up to the widest row
-                sp = np.asarray(res[7])
-                counters["spec_rounds"] += int(sp[0])
-                counters["spec_emitted"] += int(sp[1])
-                w = int(max(1, (new_state[2] - self.lens).max()))
-                outs = np.asarray(out[:, :w])
-            else:
-                outs = np.asarray(out[:, :n])  # only executed cols
-            bad = np.asarray(bad_d)
-            dt = time.perf_counter() - t_start
-            if monitor.record(self._bursts, dt):
+            with spans.span("engine.burst", live=len(active)) as burst:
+                if plan is not None:
+                    stall = plan.take_slow(self._round_no)
+                    if stall > 0.0:
+                        counters["faults_slow"] += 1
+                        plan.note("slow", round=self._round_no,
+                                  seconds=stall)
+                        time.sleep(stall)
+                with spans.span("engine.burst.prepare"):
+                    state = np.zeros((11 if self.spec_k else 10,
+                                      self.slots), np.int32)
+                    state[0, :] = self.tok[:, 0]
+                    state[1], state[2], state[3] = (self.pos, self.lens,
+                                                    self.limit)
+                    state[4] = self.done
+                    state[5, 0], state[6, 0] = n_max, wave
+                    state[7, 0] = poison_rel
+                    state[8] = self.kv_levels
+                    state[9, 0] = ovf_rel
+                    if self.spec_k:
+                        state[10] = self._spec_rows
+                    cnts = jnp.asarray(self._cnt) if self._use_pen else None
+                    table, state_in = (self._table_device(),
+                                       jnp.asarray(state))
+                with spans.span("engine.burst.dispatch"):
+                    res = self._burst(self.params, self.caches, table,
+                                      state_in, cnts, key)
+                with spans.span("engine.burst.readback"):
+                    out, n, state_d, self.caches, key2, bad_d, fl_d = res[:7]
+                    n = int(n)                    # blocks on the burst
+                    new_state = np.array(state_d)
+                    if self.spec_k:
+                        # packed layout: row b's accepted tokens fill
+                        # out[b, :lens-growth]; download up to the widest
+                        # row
+                        sp = np.asarray(res[7])
+                        counters["spec_rounds"] += int(sp[0])
+                        counters["spec_emitted"] += int(sp[1])
+                        w = int(max(1, (new_state[2] - self.lens).max()))
+                        outs = np.asarray(out[:, :w])
+                    else:
+                        outs = np.asarray(out[:, :n])  # only executed cols
+                    bad = np.asarray(bad_d)
+                burst.set(rounds=n)
+            if monitor.record(self._bursts, burst.seconds):
                 counters["stragglers"] += 1
-            if bad.sum():
-                if plan is not None and plan.mask_poison:
-                    counters["poisoned_rounds"] += int(bad.max())
-                    plan.note("poison", round=self._round_no,
-                              rows=np.nonzero(bad)[0].tolist())
-                else:
-                    raise PoisonedLogitsError(
-                        f"non-finite decode logits at round "
-                        f"{self._round_no} (rows "
-                        f"{np.nonzero(bad)[0].tolist()}); no "
-                        f"masking fault harness is active")
-            self.tok = new_state[0][:, None].copy()
-            self.pos = new_state[1]
-            if self.temperature > 0.0:
-                key = key2
-                self._key = key
-            total_ran = 0
-            for b in active:
-                # rounds this row actually ran = its live-length growth
-                ran = int(new_state[2][b]) - int(self.lens[b])
-                emitted = [int(t) for t in outs[b, :ran]]
-                self._emitted[b].extend(emitted)
-                if self.journal is not None and emitted:
-                    # the per-burst delta is the crash-consistency
-                    # quantum: at most one burst of tokens is ever lost,
-                    # and greedy determinism regenerates it bit-exactly
-                    self.journal.append("tokens", rid=self._req[b].rid,
-                                        replica=self.replica_id,
-                                        toks=emitted)
-                if self._use_pen and emitted:
-                    v = self._cnt.shape[1]
-                    np.add.at(self._cnt[b],
-                              np.asarray(emitted, np.int64) % v, 1)
-                self._occ_accum += ran
-                total_ran += ran
-            if n > 0 and total_ran == 0:
-                raise EngineStuckError(
-                    f"decode burst executed {n} rounds without "
-                    f"advancing any of {len(active)} live rows",
-                    self._diag())
+            with spans.span("engine.burst.bookkeeping"):
+                if bad.sum():
+                    if plan is not None and plan.mask_poison:
+                        counters["poisoned_rounds"] += int(bad.max())
+                        plan.note("poison", round=self._round_no,
+                                  rows=np.nonzero(bad)[0].tolist())
+                    else:
+                        raise PoisonedLogitsError(
+                            f"non-finite decode logits at round "
+                            f"{self._round_no} (rows "
+                            f"{np.nonzero(bad)[0].tolist()}); no "
+                            f"masking fault harness is active")
+                self.tok = new_state[0][:, None].copy()
+                self.pos = new_state[1]
+                if self.temperature > 0.0:
+                    key = key2
+                    self._key = key
+                total_ran = 0
+                for b in active:
+                    # rounds this row actually ran = its live-length growth
+                    ran = int(new_state[2][b]) - int(self.lens[b])
+                    emitted = [int(t) for t in outs[b, :ran]]
+                    self._emitted[b].extend(emitted)
+                    if self.journal is not None and emitted:
+                        # the per-burst delta is the crash-consistency
+                        # quantum: at most one burst of tokens is ever lost,
+                        # and greedy determinism regenerates it bit-exactly
+                        self.journal.append("tokens", rid=self._req[b].rid,
+                                            replica=self.replica_id,
+                                            toks=emitted)
+                    if self._use_pen and emitted:
+                        v = self._cnt.shape[1]
+                        np.add.at(self._cnt[b],
+                                  np.asarray(emitted, np.int64) % v, 1)
+                    self._occ_accum += ran
+                    total_ran += ran
+                if n > 0 and total_ran == 0:
+                    raise EngineStuckError(
+                        f"decode burst executed {n} rounds without "
+                        f"advancing any of {len(active)} live rows",
+                        self._diag())
+                if self.escalate is not None:
+                    self.flag_pressure += np.asarray(fl_d, np.int64)
+                    if plan is not None and 0 <= ovf_rel < n:
+                        counters["faults_overflow"] = counters.get(
+                            "faults_overflow", 0) + 1
+                        plan.note("overflow",
+                                  round=self._round_no + ovf_rel,
+                                  scale=plan.overflow_scale)
+                self.lens = new_state[2]
+                self.done = new_state[3].astype(bool)
+                self._round_no += n
+                self._decode_rounds += n
+                self._bursts += 1
+                progress += n
+                for b in active:
+                    if self.done[b]:
+                        self._finish(b, self._round_no, self._results)
+                        progress += 1
             if self.escalate is not None:
-                self.flag_pressure += np.asarray(fl_d, np.int64)
-                if plan is not None and 0 <= ovf_rel < n:
-                    counters["faults_overflow"] = counters.get(
-                        "faults_overflow", 0) + 1
-                    plan.note("overflow",
-                              round=self._round_no + ovf_rel,
-                              scale=plan.overflow_scale)
-            self.lens = new_state[2]
-            self.done = new_state[3].astype(bool)
-            self._round_no += n
-            self._decode_rounds += n
-            self._bursts += 1
-            progress += n
-            for b in active:
-                if self.done[b]:
-                    self._finish(b, self._round_no, self._results)
-                    progress += 1
-            if self.escalate is not None:
-                self.caches = self._maybe_escalate(
-                    active, self._round_no, self.caches, counters)
+                with spans.span("engine.escalate"):
+                    self.caches = self._maybe_escalate(
+                        active, self._round_no, self.caches, counters)
         elif still_prefilling:
             self._round_no += 1    # prefill-only round (no decoders yet)
         elif self._pending:
@@ -1586,7 +1648,6 @@ class ContinuousEngine:
                 nxt.append(self._release_at)
             self._round_no = max(self._round_no + 1, min(nxt))
         watchdog.tick(progress > 0, self._diag)
-        return self.has_work()
 
     def finalize(self):
         """Close out a drained (or abandoned) run: release fault-plan
@@ -1620,6 +1681,9 @@ class ContinuousEngine:
             "deadline_miss_rate": (misses / len(dl)) if dl else 0.0,
             "straggler_ewma_s": self.monitor.ewma,
             **counters,
+            # launch/spans.py records since start(), oldest first
+            "spans": self.spans.records(),
+            "spans_dropped": self.spans.dropped,
         }
         if self.spec_k:
             lr = counters["spec_rounds"]
@@ -1778,7 +1842,9 @@ class ReplicatedEngine:
         replicas, interleaved one step at a time.  Returns
         ``(finished, stats)`` with ``finished`` in input order;
         ``stats["replicas"]`` keeps each replica's own record,
-        ``stats["pool"]`` the aggregated allocator view, and the
+        ``stats["spans"]`` each replica's span list (one list per
+        replica, never merged), ``stats["pool"]`` the aggregated
+        allocator view, and the
         ``ha_*`` fields + ``stats["heartbeats"]`` the fleet's
         fault-tolerance story."""
         from ..models.paged import aggregate_stats
@@ -1837,6 +1903,8 @@ class ReplicatedEngine:
             results.update(res)
             st["replica_status"] = self.heartbeats[i]["status"]
             per.append(st)
+        # one span list per replica: each is on its own engine's ids
+        spans = [s.pop("spans") for s in per]
         dr = sum(s["decode_rounds"] for s in per)
         stats = {
             "replicas_n": len(self.engines),
@@ -1853,6 +1921,7 @@ class ReplicatedEngine:
             "pool": aggregate_stats(self.allocators),
             "replicas": per,
             "heartbeats": [dict(h) for h in self.heartbeats],
+            "spans": spans,
             **self._ha,
         }
         dl = stats["deadline_total"]

@@ -428,34 +428,38 @@ def gqa_attention(x, params, policy, *, n_heads, n_kv_heads, head_dim,
     instead of the projected residual contribution.
     """
     b, s, d = x.shape
-    q = tp.tp_einsum("bsd,de->bse", x, params["wq"], policy)
-    q = q.reshape(b, s, n_heads, head_dim)
-    kv_src = kv_states if kv_states is not None else x
-    t = kv_src.shape[1]
-    k = tp.tp_einsum("bsd,de->bse", kv_src, params["wk"], policy)
-    v = tp.tp_einsum("bsd,de->bse", kv_src, params["wv"], policy)
-    k = k.reshape(b, t, n_kv_heads, head_dim)
-    v = v.reshape(b, t, n_kv_heads, head_dim)
+    with jax.named_scope("attn.proj"):
+        q = tp.tp_einsum("bsd,de->bse", x, params["wq"], policy)
+        q = q.reshape(b, s, n_heads, head_dim)
+        kv_src = kv_states if kv_states is not None else x
+        t = kv_src.shape[1]
+        k = tp.tp_einsum("bsd,de->bse", kv_src, params["wk"], policy)
+        v = tp.tp_einsum("bsd,de->bse", kv_src, params["wv"], policy)
+        k = k.reshape(b, t, n_kv_heads, head_dim)
+        v = v.reshape(b, t, n_kv_heads, head_dim)
 
-    if qk_norm:
-        q = rmsnorm(q, params["q_norm"], norm_eps)
-        k = rmsnorm(k, params["k_norm"], norm_eps)
-    if use_rope:
-        kv_pos = positions if kv_states is None else jnp.arange(t)
-        q = apply_rope(q.swapaxes(1, 2), positions, rope_theta).swapaxes(1, 2)
-        k = apply_rope(k.swapaxes(1, 2), kv_pos, rope_theta).swapaxes(1, 2)
+        if qk_norm:
+            q = rmsnorm(q, params["q_norm"], norm_eps)
+            k = rmsnorm(k, params["k_norm"], norm_eps)
+        if use_rope:
+            kv_pos = positions if kv_states is None else jnp.arange(t)
+            q = apply_rope(q.swapaxes(1, 2), positions,
+                           rope_theta).swapaxes(1, 2)
+            k = apply_rope(k.swapaxes(1, 2), kv_pos,
+                           rope_theta).swapaxes(1, 2)
 
-    q = shard(q.swapaxes(1, 2), bspec("model", None, None))
-    k = shard(k.swapaxes(1, 2), bspec("model", None, None))
-    v = shard(v.swapaxes(1, 2), bspec("model", None, None))
+        q = shard(q.swapaxes(1, 2), bspec("model", None, None))
+        k = shard(k.swapaxes(1, 2), bspec("model", None, None))
+        v = shard(v.swapaxes(1, 2), bspec("model", None, None))
 
     tp_size = _head_shard_size(mesh, n_heads, n_kv_heads)
 
     def _attend(fn, head_ops=(), rep_ops=(), q_op=None):
         qq = q if q_op is None else q_op
-        if tp_size is None:
-            return fn(qq, *head_ops, *rep_ops)
-        return _headshard_call(mesh, fn, qq, head_ops, rep_ops)
+        with jax.named_scope("attn.kernel"):
+            if tp_size is None:
+                return fn(qq, *head_ops, *rep_ops)
+            return _headshard_call(mesh, fn, qq, head_ops, rep_ops)
 
     new_cache = None
     kv_flags = jnp.zeros((b, 2), jnp.int32)  # OF, UF write counts per row
@@ -483,19 +487,20 @@ def gqa_attention(x, params, policy, *, n_heads, n_kv_heads, head_dim,
             k, kf = quantize_kv_rows(k, esc_fmts, kv_levels)
             v, vf = quantize_kv_rows(v, esc_fmts, kv_levels)
             kv_flags = kf + vf
-        if paged:
-            # paged cache: K/V scatter through the block table into the
-            # shared page pool instead of a per-row contiguous strip
-            new_cache = PagedKVCache(
-                paged_update_rows(cache.k_pool, cache.block_table, k,
-                                  cache_pos),
-                paged_update_rows(cache.v_pool, cache.block_table, v,
-                                  cache_pos),
-                cache.block_table)
-        else:
-            ck = update_cache_rows(cache.k, k, cache_pos, axis=2)
-            cv = update_cache_rows(cache.v, v, cache_pos, axis=2)
-            new_cache = KVCache(ck, cv)
+        with jax.named_scope("kv.write"):
+            if paged:
+                # paged cache: K/V scatter through the block table into the
+                # shared page pool instead of a per-row contiguous strip
+                new_cache = PagedKVCache(
+                    paged_update_rows(cache.k_pool, cache.block_table, k,
+                                      cache_pos),
+                    paged_update_rows(cache.v_pool, cache.block_table, v,
+                                      cache_pos),
+                    cache.block_table)
+            else:
+                ck = update_cache_rows(cache.k, k, cache_pos, axis=2)
+                cv = update_cache_rows(cache.v, v, cache_pos, axis=2)
+                new_cache = KVCache(ck, cv)
         if verify and s > 1:
             # speculative verify: fold the s chunk queries into the batch
             # dimension and take the exact decode read path — query i of
@@ -617,12 +622,13 @@ def gqa_attention(x, params, policy, *, n_heads, n_kv_heads, head_dim,
     if return_attend:
         return out, new_cache
 
-    out = out.swapaxes(1, 2).reshape(b, s, n_heads * head_dim)
-    if tp_size is None:
-        proj = tp.tp_einsum("bse,ed->bsd", out, params["wo"], policy)
-    else:
-        proj = _row_parallel_wo(mesh, out, params["wo"], policy)
-    proj = shard(proj, residual_spec())
+    with jax.named_scope("attn.proj"):
+        out = out.swapaxes(1, 2).reshape(b, s, n_heads * head_dim)
+        if tp_size is None:
+            proj = tp.tp_einsum("bse,ed->bsd", out, params["wo"], policy)
+        else:
+            proj = _row_parallel_wo(mesh, out, params["wo"], policy)
+        proj = shard(proj, residual_spec())
     if esc_fmts is not None:
         return proj, new_cache, kv_flags
     return proj, new_cache

@@ -108,64 +108,69 @@ def moe_core(x_flat, params, cfg: MoEConfig, policy, *,
     cap = _capacity(t, cfg)
 
     # --- routing (f32; COMP group) ---------------------------------------
-    logits = jnp.einsum("td,de->te", x_flat.astype(jnp.float32),
-                        params["router"].astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k)              # [T, k]
-    if cfg.router_norm_topk:
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum("td,de->te", x_flat.astype(jnp.float32),
+                            params["router"].astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, k)          # [T, k]
+        if cfg.router_norm_topk:
+            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
-    # aux load-balancing loss (Switch-style)
-    me = probs.mean(axis=0)                            # mean prob per expert
-    onehot_top1 = jax.nn.one_hot(idx[:, 0], e_total)
-    ce = onehot_top1.mean(axis=0)                      # dispatch fraction
-    aux = e_total * jnp.sum(me * ce)
+        # aux load-balancing loss (Switch-style)
+        me = probs.mean(axis=0)                        # mean prob per expert
+        onehot_top1 = jax.nn.one_hot(idx[:, 0], e_total)
+        ce = onehot_top1.mean(axis=0)                  # dispatch fraction
+        aux = e_total * jnp.sum(me * ce)
 
     # --- sort-based dispatch ----------------------------------------------
-    flat_e = idx.reshape(-1)                           # [T*k]
-    order = jnp.argsort(flat_e, stable=True)
-    sorted_e = flat_e[order]
-    first = jnp.searchsorted(sorted_e, jnp.arange(e_total), side="left")
-    rank = jnp.arange(t * k) - first[sorted_e]
-    keep = rank < cap
-    slot = jnp.where(keep, sorted_e * cap + rank, e_total * cap)
-    src_tok = order // k
-    buf = jnp.zeros((e_total * cap + 1, d), x_flat.dtype)
-    buf = buf.at[slot].set(x_flat[src_tok], mode="drop",
-                           unique_indices=True)
-    buf = buf[:-1].reshape(e_total, cap, d)
+    with jax.named_scope("moe.dispatch"):
+        flat_e = idx.reshape(-1)                       # [T*k]
+        order = jnp.argsort(flat_e, stable=True)
+        sorted_e = flat_e[order]
+        first = jnp.searchsorted(sorted_e, jnp.arange(e_total), side="left")
+        rank = jnp.arange(t * k) - first[sorted_e]
+        keep = rank < cap
+        slot = jnp.where(keep, sorted_e * cap + rank, e_total * cap)
+        src_tok = order // k
+        buf = jnp.zeros((e_total * cap + 1, d), x_flat.dtype)
+        buf = buf.at[slot].set(x_flat[src_tok], mode="drop",
+                               unique_indices=True)
+        buf = buf[:-1].reshape(e_total, cap, d)
 
-    # --- EP exchange -------------------------------------------------------
-    # all_to_all(split=0, concat=0, tiled=False) swaps the leading
-    # destination-shard axis for a source-shard axis in place.
-    if ep_axis is not None and ep_size > 1:
-        # [E, C, D] -> [M(dest), E_loc, C, D] -> a2a -> [M(src), E_loc, C, D]
-        buf = buf.reshape(ep_size, e_loc, cap, d)
-        buf = jax.lax.all_to_all(buf, ep_axis, split_axis=0, concat_axis=0,
-                                 tiled=False)
-        # -> [E_loc, M(src), C, D] -> [E_loc, M*C, D]
-        buf = buf.swapaxes(0, 1).reshape(e_loc, ep_size * cap, d)
+        # --- EP exchange ---------------------------------------------------
+        # all_to_all(split=0, concat=0, tiled=False) swaps the leading
+        # destination-shard axis for a source-shard axis in place.
+        if ep_axis is not None and ep_size > 1:
+            # [E, C, D] -> [M(dest), E_loc, C, D] -> a2a
+            #   -> [M(src), E_loc, C, D]
+            buf = buf.reshape(ep_size, e_loc, cap, d)
+            buf = jax.lax.all_to_all(buf, ep_axis, split_axis=0,
+                                     concat_axis=0, tiled=False)
+            # -> [E_loc, M(src), C, D] -> [E_loc, M*C, D]
+            buf = buf.swapaxes(0, 1).reshape(e_loc, ep_size * cap, d)
 
-    out = _expert_ffn(buf, params["w_gate"], params["w_up"],
-                      params["w_down"], policy)
+    with jax.named_scope("moe.experts"):
+        out = _expert_ffn(buf, params["w_gate"], params["w_up"],
+                          params["w_down"], policy)
 
-    if ep_axis is not None and ep_size > 1:
-        # [E_loc, M(src), C, D] -> [M(src=dest now), E_loc, C, D] -> a2a
-        out = out.reshape(e_loc, ep_size, cap, d).swapaxes(0, 1)
-        out = jax.lax.all_to_all(out, ep_axis, split_axis=0, concat_axis=0,
-                                 tiled=False)
-        # [M(expert-shard), E_loc, C, D] == [E, C, D] in expert-major order
-        out = out.reshape(e_total * cap, d)
-    else:
-        out = out.reshape(e_total * cap, d)
+    with jax.named_scope("moe.combine"):
+        if ep_axis is not None and ep_size > 1:
+            # [E_loc, M(src), C, D] -> [M(src=dest now), E_loc, C, D] -> a2a
+            out = out.reshape(e_loc, ep_size, cap, d).swapaxes(0, 1)
+            out = jax.lax.all_to_all(out, ep_axis, split_axis=0,
+                                     concat_axis=0, tiled=False)
+            # [M(expert-shard), E_loc, C, D] == [E, C, D], expert-major
+            out = out.reshape(e_total * cap, d)
+        else:
+            out = out.reshape(e_total * cap, d)
 
-    # --- combine ------------------------------------------------------------
-    out = jnp.concatenate([out, jnp.zeros((1, d), out.dtype)], axis=0)
-    gathered = out[slot]                               # [T*k, D] (sorted order)
-    unsort = jnp.argsort(order, stable=True)
-    gathered = gathered[unsort].reshape(t, k, d)
-    y = jnp.einsum("tkd,tk->td", gathered.astype(jnp.float32),
-                   gates.astype(jnp.float32)).astype(x_flat.dtype)
+        out = jnp.concatenate([out, jnp.zeros((1, d), out.dtype)], axis=0)
+        gathered = out[slot]                       # [T*k, D] (sorted order)
+        unsort = jnp.argsort(order, stable=True)
+        gathered = gathered[unsort].reshape(t, k, d)
+        y = jnp.einsum("tkd,tk->td", gathered.astype(jnp.float32),
+                       gates.astype(jnp.float32)).astype(x_flat.dtype)
+
     return y, aux
 
 

@@ -584,12 +584,13 @@ class Model:
         w = params["embed"] if cfg.tie_embeddings else params["lm_head"]
         spec_str = "bsd,vd->bsv" if cfg.tie_embeddings else "bsd,dv->bsv"
         out_fmt = "fp16alt" if cfg.ce_dtype == "fp16alt" else "fp32"
-        lg = tp.tp_einsum(spec_str, x, w, policy, out_fmt=out_fmt)
-        lg = softcap(lg, cfg.logit_softcap)
-        vpad = padded_vocab(cfg.vocab)
-        if vpad != cfg.vocab:  # mask the pad tail (never predicted)
-            lg = jnp.where(jnp.arange(vpad) < cfg.vocab, lg, -1e30)
-        return shard(lg, bspec(None, "model"))
+        with jax.named_scope("head"):
+            lg = tp.tp_einsum(spec_str, x, w, policy, out_fmt=out_fmt)
+            lg = softcap(lg, cfg.logit_softcap)
+            vpad = padded_vocab(cfg.vocab)
+            if vpad != cfg.vocab:  # mask the pad tail (never predicted)
+                lg = jnp.where(jnp.arange(vpad) < cfg.vocab, lg, -1e30)
+            return shard(lg, bspec(None, "model"))
 
     # -- stacks ------------------------------------------------------------
     def _run_stack(self, params, x, *, positions, mesh=None, caches=None,
@@ -1167,25 +1168,26 @@ class Model:
                              kv_levels=kv_levels, kv_scale=kv_scale)
         lg, caches = r[0], r[1]
         kv_flags = r[2] if esc_fmts is not None else None
-        lgv = lg[:, -1]
-        if poison is not None:
-            lgv = jnp.where(jnp.asarray(poison), jnp.nan, lgv)
-        bad = None
-        if guard:
-            lgv, bad = sanitize_logits(lgv)
-        if counts is not None:
-            lgv = apply_penalties(lgv, counts,
-                                  repetition_penalty=repetition_penalty,
-                                  presence_penalty=presence_penalty)
-        if temperature is not None and temperature > 0.0:
-            key, sk = jax.random.split(jax.random.key(0)
-                                       if key is None else key)
-            nxt = sample_token(lgv, sk, temperature=temperature,
-                               top_k=top_k, top_p=top_p)[:, None]
-        else:
-            nxt = jnp.argmax(lgv, -1).astype(jnp.int32)[:, None]
-        if stop_token is not None:
-            nxt = jnp.where(done[:, None], stop_token, nxt)
+        with jax.named_scope("sample"):
+            lgv = lg[:, -1]
+            if poison is not None:
+                lgv = jnp.where(jnp.asarray(poison), jnp.nan, lgv)
+            bad = None
+            if guard:
+                lgv, bad = sanitize_logits(lgv)
+            if counts is not None:
+                lgv = apply_penalties(lgv, counts,
+                                      repetition_penalty=repetition_penalty,
+                                      presence_penalty=presence_penalty)
+            if temperature is not None and temperature > 0.0:
+                key, sk = jax.random.split(jax.random.key(0)
+                                           if key is None else key)
+                nxt = sample_token(lgv, sk, temperature=temperature,
+                                   top_k=top_k, top_p=top_p)[:, None]
+            else:
+                nxt = jnp.argmax(lgv, -1).astype(jnp.int32)[:, None]
+            if stop_token is not None:
+                nxt = jnp.where(done[:, None], stop_token, nxt)
         ret = (nxt, lg, caches, key)
         if guard:
             ret += (bad,)

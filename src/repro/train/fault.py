@@ -40,6 +40,7 @@ Replica-level counterparts (the data-parallel serving fleet of
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from typing import Callable, Optional
@@ -47,7 +48,11 @@ from typing import Callable, Optional
 
 class StragglerMonitor:
     """EWMA of step wall-clock; flags steps slower than ``threshold`` x the
-    running mean (after a warmup)."""
+    running mean (after a warmup).  ``flagged`` keeps the last
+    ``FLAGGED_KEPT`` flagged ``(step, dt, ewma)`` entries, so a
+    long-running server's monitor stays bounded."""
+
+    FLAGGED_KEPT = 256
 
     def __init__(self, alpha: float = 0.1, threshold: float = 2.0,
                  warmup: int = 5):
@@ -56,7 +61,8 @@ class StragglerMonitor:
         self.warmup = warmup
         self.ewma: Optional[float] = None
         self.n = 0
-        self.flagged: list = []
+        self.flagged: collections.deque = collections.deque(
+            maxlen=self.FLAGGED_KEPT)
 
     def record(self, step: int, dt: float) -> bool:
         self.n += 1
