@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.formats import get_format
+from ..core.ops import storage_dtype
 from ..core.policy import PrecisionPolicy, get_policy
 from . import autotune
 from .tp_matmul import tp_matmul_pallas, DEFAULT_BLOCK
@@ -22,6 +23,7 @@ from .tp_quant import tp_quantize_pallas, cast_and_pack_pallas
 from .flash_attention import flash_attention_pallas
 from .decode_attention import decode_attention_pallas
 from .dotp_ex import dotp_ex_pallas
+from .grouped_ffn import grouped_ffn_pallas
 
 
 def _pad_to(x, mults, axes):
@@ -345,6 +347,29 @@ def decode_attention(q, k, v, *, kv_len, policy=None,
         return (o[:, :group].reshape(b, hkv, group, d).reshape(b, h, 1, d),
                 _reduce_flag_cells(fl, b, hkv))
     return o[:, :group].reshape(b, hkv, group, d).reshape(b, h, 1, d)
+
+
+def grouped_ffn(x, expert_ids, w_gate, w_up, w_down, layer, *, policy,
+                interpret: Optional[bool] = None,
+                debug_fetches: bool = False):
+    """Grouped expert SwiGLU over expert-sorted rows (native-mode policy):
+    x [N, D], expert_ids [N] non-decreasing, stacked weights
+    [L, E, D, F] / [L, E, F, D], ``layer`` the traced index into them.
+    Dtypes follow ``core.ops.tp_einsum``/``tp_elementwise`` in native mode.
+    Returns ``(y [N, D], n_active)`` (``grouped_ffn_pallas``)."""
+    policy = get_policy(policy)
+    assert policy.mode == "native", policy.mode
+    mp = policy.matmul
+    out = mp.resolved_out()
+    acc = storage_dtype(mp.acc_fmt, "native")
+    if policy.narrow_partials and out.width < mp.acc_fmt.width:
+        acc = out.native_dtype
+    return grouped_ffn_pallas(
+        x, expert_ids, w_gate, w_up, w_down, layer,
+        src_dtype=mp.src_fmt.native_dtype,
+        acc_dtype=acc, out_dtype=out.native_dtype,
+        elem_dtype=storage_dtype(policy.elem_fmt, "native"),
+        interpret=resolve_interpret(interpret), debug_fetches=debug_fetches)
 
 
 def dotp_ex(a, b, *, policy=None, interpret: Optional[bool] = None):

@@ -480,3 +480,26 @@ def dotp_sequential_ref(a, b, *, src_fmt="fp16", acc_fmt="fp32"):
 
     out, _ = jax.lax.scan(step, jnp.float32(0.0), (qa, qb))
     return out
+
+
+def grouped_ffn_ref(x, expert_ids, w_gate, w_up, w_down, layer, *,
+                    src_dtype=jnp.bfloat16, acc_dtype=jnp.float32,
+                    out_dtype=jnp.bfloat16, elem_dtype=jnp.float32):
+    """Oracle of the grouped expert SwiGLU: each row ``x[n]`` through
+    expert ``expert_ids[n]`` of stacked layer ``layer``, with the kernel's
+    roundings (``src`` operands, ``acc`` accumulation, ``g``/``u`` stored
+    in ``out``, ``silu(g) * u`` in ``elem`` then ``src``, result in
+    ``out``).  Every expert runs over every row and each row keeps its
+    own expert's output — no grouping, no tiles."""
+    dot = lambda a, b: jax.lax.dot_general(
+        a, b, (((1,), (0,)), ((), ())), preferred_element_type=acc_dtype)
+    xs = x.astype(src_dtype)
+    y = jnp.zeros(x.shape, out_dtype)
+    w = lambda a, e: a[layer, e].astype(src_dtype)
+    for e in range(w_gate.shape[1]):
+        g = dot(xs, w(w_gate, e)).astype(out_dtype)
+        u = dot(xs, w(w_up, e)).astype(out_dtype)
+        h = jax.nn.silu(g.astype(elem_dtype)) * u
+        ye = dot(h.astype(src_dtype), w(w_down, e)).astype(out_dtype)
+        y = jnp.where((expert_ids == e)[:, None], ye, y)
+    return y

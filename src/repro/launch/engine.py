@@ -592,10 +592,14 @@ class ContinuousEngine:
                 poison_at=state[7, 0], guard=True, **esc_kw)
             out, n, tok, caches, pos, lens, done, key = r[:8]
             bad = r[8]
-            fl = (r[-1] if esc_fmts is not None
+            fl = (r[-2] if esc_fmts is not None
                   else jnp.zeros((slots, 2), jnp.int32))
+            # the experts read ride the state readback (row 4, column 0):
+            # no transfer of their own
+            n_read = jnp.zeros_like(pos).at[0].set(r[-1])
             return (out, n,
-                    jnp.stack([tok[:, 0], pos, lens, done.astype(jnp.int32)]),
+                    jnp.stack([tok[:, 0], pos, lens, done.astype(jnp.int32),
+                               n_read]),
                     caches, key, bad, fl)
 
         spec_k_, dr_, dpol_ = self.spec_k, draft_repeats, self.draft_policy
@@ -1572,7 +1576,10 @@ class ContinuousEngine:
                     else:
                         outs = np.asarray(out[:, :n])  # only executed cols
                     bad = np.asarray(bad_d)
-                burst.set(rounds=n)
+                if self.spec_k:
+                    burst.set(rounds=n)
+                else:
+                    burst.set(rounds=n, experts_read=int(new_state[4, 0]))
             if monitor.record(self._bursts, burst.seconds):
                 counters["stragglers"] += 1
             with spans.span("engine.burst.bookkeeping"):

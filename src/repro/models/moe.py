@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ..core import ops as tp
+from ..core.policy import get_policy
 from .layers import batch_axes, bspec, dense_init, residual_spec, shard
 
 
@@ -93,6 +94,35 @@ def _expert_ffn(buf, w_gate, w_up, w_down, policy):
     return tp.tp_einsum("ecf,efd->ecd", h, w_down, policy)
 
 
+def _route(x_flat, params, cfg: MoEConfig):
+    """Router (f32; COMP group): top-k gates and experts per token, and
+    the Switch-style load-balancing loss."""
+    with jax.named_scope("moe.router"):
+        logits = jnp.einsum("td,de->te", x_flat.astype(jnp.float32),
+                            params["router"].astype(jnp.float32))
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, cfg.top_k)   # [T, k]
+        if cfg.router_norm_topk:
+            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+
+        # aux load-balancing loss (Switch-style)
+        me = probs.mean(axis=0)                        # mean prob per expert
+        onehot_top1 = jax.nn.one_hot(idx[:, 0], cfg.n_experts)
+        ce = onehot_top1.mean(axis=0)                  # dispatch fraction
+        aux = cfg.n_experts * jnp.sum(me * ce)
+    return gates, idx, aux
+
+
+def _unsort_combine(rows, order, gates, dtype):
+    """Expert outputs in sorted (expert-major) order [T*k, D] -> the
+    gate-weighted sum per token [T, D]."""
+    t, k = gates.shape
+    unsort = jnp.argsort(order, stable=True)
+    gathered = rows[unsort].reshape(t, k, rows.shape[-1])
+    return jnp.einsum("tkd,tk->td", gathered.astype(jnp.float32),
+                      gates.astype(jnp.float32)).astype(dtype)
+
+
 def moe_core(x_flat, params, cfg: MoEConfig, policy, *,
              ep_axis: Optional[str] = None, ep_size: int = 1):
     """x_flat [T, D] -> (y [T, D], aux_loss scalar).
@@ -107,20 +137,7 @@ def moe_core(x_flat, params, cfg: MoEConfig, policy, *,
     k = cfg.top_k
     cap = _capacity(t, cfg)
 
-    # --- routing (f32; COMP group) ---------------------------------------
-    with jax.named_scope("moe.router"):
-        logits = jnp.einsum("td,de->te", x_flat.astype(jnp.float32),
-                            params["router"].astype(jnp.float32))
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, idx = jax.lax.top_k(probs, k)          # [T, k]
-        if cfg.router_norm_topk:
-            gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-
-        # aux load-balancing loss (Switch-style)
-        me = probs.mean(axis=0)                        # mean prob per expert
-        onehot_top1 = jax.nn.one_hot(idx[:, 0], e_total)
-        ce = onehot_top1.mean(axis=0)                  # dispatch fraction
-        aux = e_total * jnp.sum(me * ce)
+    gates, idx, aux = _route(x_flat, params, cfg)
 
     # --- sort-based dispatch ----------------------------------------------
     with jax.named_scope("moe.dispatch"):
@@ -165,20 +182,64 @@ def moe_core(x_flat, params, cfg: MoEConfig, policy, *,
             out = out.reshape(e_total * cap, d)
 
         out = jnp.concatenate([out, jnp.zeros((1, d), out.dtype)], axis=0)
-        gathered = out[slot]                       # [T*k, D] (sorted order)
-        unsort = jnp.argsort(order, stable=True)
-        gathered = gathered[unsort].reshape(t, k, d)
-        y = jnp.einsum("tkd,tk->td", gathered.astype(jnp.float32),
-                       gates.astype(jnp.float32)).astype(x_flat.dtype)
+        y = _unsort_combine(out[slot], order, gates, x_flat.dtype)
 
     return y, aux
 
 
+def grouped_path(cfg: Optional[MoEConfig], policy, mesh) -> bool:
+    """Whether serving runs the routed experts through the grouped kernel
+    (``moe_core_grouped``) rather than the capacity einsum.  It follows
+    what the program can observe: a compiled-kernel platform (not the
+    CPU, as ``kernels.ops.resolve_backend("auto")``), drop-free dispatch
+    (finite capacity's drops are part of its semantics), a native-mode
+    policy (emulate mode snaps operands), and a single-device program (no
+    expert-parallel or data axis to partition the kernel over)."""
+    return (cfg is not None and cfg.capacity_factor is None
+            and get_policy(policy).mode == "native"
+            and (mesh is None or mesh.size == 1)
+            and jax.default_backend() != "cpu")
+
+
+def moe_core_grouped(x_flat, params, experts, cfg: MoEConfig, policy):
+    """x_flat [T, D] -> (y [T, D], experts read): the serving path.
+
+    ``experts`` is ``(w_gate, w_up, w_down, layer)``: the STACKED
+    [L, E, D, F] / [L, E, F, D] expert weights and this layer's index
+    into them, so that a caller inside the layer scan never slices one
+    layer's experts out.  The T*k assignments, sorted by expert, run
+    through ``kernels.grouped_ffn``, which reads the weights of only the
+    experts that received a row; the count of those comes back with
+    ``y``."""
+    from ..kernels import ops as kops
+    w_gate, w_up, w_down, layer = experts
+    gates, idx, _ = _route(x_flat, params, cfg)
+    with jax.named_scope("moe.dispatch"):
+        flat_e = idx.reshape(-1)                       # [T*k]
+        order = jnp.argsort(flat_e, stable=True)
+        sorted_e = flat_e[order]
+        rows = x_flat[order // cfg.top_k]              # [T*k, D] sorted
+    with jax.named_scope("moe.experts"):
+        out, n_read = kops.grouped_ffn(rows, sorted_e, w_gate, w_up,
+                                       w_down, layer, policy=policy)
+    with jax.named_scope("moe.combine"):
+        y = _unsort_combine(out, order, gates, x_flat.dtype)
+    return y, n_read
+
+
 def moe_block(x, params, cfg: MoEConfig, policy, *, mesh=None,
-              ep_axis: Optional[str] = "model"):
+              ep_axis: Optional[str] = "model", serving: bool = False,
+              experts=None):
     """x [B, S, D] -> (y, aux).  Uses shard_map EP when a mesh with the
     ``ep_axis`` is provided (production path); plain local dispatch
-    otherwise (tests / single device)."""
+    otherwise (tests / single device).
+
+    ``serving=True`` (the serving entry points, which train nothing)
+    makes ``aux`` the number of experts whose weights the layer read in
+    place of the load-balancing loss: with ``experts`` (the stacked
+    weights and layer index, see ``moe_core_grouped``; the caller passes
+    them where ``grouped_path`` holds) only the routed experts, on the
+    capacity einsum every expert."""
     b, s, d = x.shape
     y_shared = None
     if cfg.n_shared:
@@ -212,8 +273,12 @@ def moe_block(x, params, cfg: MoEConfig, policy, *, mesh=None,
             check_vma=False,
         )(xf, routed)
         aux = aux.mean()
+    elif experts is not None:
+        y, aux = moe_core_grouped(xf, routed, experts, cfg, policy)
     else:
         y, aux = moe_core(xf, routed, cfg, policy)
+    if serving and experts is None:
+        aux = jnp.asarray(cfg.n_experts, jnp.int32)
 
     y = y.reshape(b, s, d)
     y = shard(y, residual_spec())

@@ -320,18 +320,24 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int,
 # ---------------------------------------------------------------------------
 # layer application
 # ---------------------------------------------------------------------------
+_EXPERT_W = ("w_gate", "w_up", "w_down")
+
+
 def apply_layer(x, p, spec: LayerSpec, cfg: ModelConfig,
                 policy: PrecisionPolicy, *, positions, mesh=None,
                 cache=None, cache_pos=None, enc_states=None,
                 shared_params=None, decode: bool = False, kv_len=None,
                 esc_fmts=None, kv_levels=None, kv_scale=None,
-                verify: bool = False):
+                verify: bool = False, serving: bool = False,
+                experts=None):
     """Returns (x, new_cache, aux_loss) — with a fourth element
     ``kv_flags`` [B, 2] (per-row OF/UF write-flag counts) when
     ``esc_fmts`` is given (escalation write path; GQA mixers only, other
     mixers contribute zeros).  ``kv_len``/``cache_pos`` may be
     per-sequence [B] vectors (ragged batches) — attention mixers mask and
-    write per row; SSM mixers have no length axis and ignore them."""
+    write per row; SSM mixers have no length axis and ignore them.
+    ``serving``/``experts``: see ``moe.moe_block`` (under ``serving`` a
+    MoE layer's aux is the number of experts it read)."""
     aux = jnp.zeros((), F32)
     new_cache: dict = {}
     kv_flags = None
@@ -421,7 +427,8 @@ def apply_layer(x, p, spec: LayerSpec, cfg: ModelConfig,
                          fp["mlp"]["down"], fp["mlp"]["b_down"], policy)
         elif spec.ffn == "moe":
             f, aux = moe_mod.moe_block(h2, fp["mlp"], cfg.moe, policy,
-                                       mesh=mesh)
+                                       mesh=mesh, serving=serving,
+                                       experts=experts)
         else:
             raise ValueError(spec.ffn)
         if spec.post_norms:
@@ -596,49 +603,84 @@ class Model:
     def _run_stack(self, params, x, *, positions, mesh=None, caches=None,
                    cache_pos=None, enc_states=None, remat: bool = False,
                    decode: bool = False, kv_len=None, esc_fmts=None,
-                   kv_levels=None, kv_scale=None, verify: bool = False):
+                   kv_levels=None, kv_scale=None, verify: bool = False,
+                   serving: bool = False):
+        """``serving`` (set by the serving entry points ``prefill_chunk``,
+        ``decode_step`` under ``decode_round``, ``verify_chunk``): MoE
+        layers take the grouped expert kernel where ``moe.grouped_path``
+        holds, and the third return is the number of experts read (int32,
+        summed over layers) in place of the load-balancing loss.  The
+        grouped path takes the pattern's expert weights whole, with the
+        layer index riding the scan, so the scan never slices them."""
         cfg = self.cfg
         shared = params.get("shared")
         esc = esc_fmts is not None
-        aux_total = jnp.zeros((), F32)
+        grouped = serving and moe_mod.grouped_path(cfg.moe, self.policy,
+                                                   mesh)
+        aux_total = jnp.zeros((), jnp.int32 if serving else F32)
         flags_total = (jnp.zeros((x.shape[0], 2), jnp.int32) if esc
                        else None)
         new_prefix, new_suffix = [], []
 
-        def run_one(x, p, c, spec):
+        def run_one(x, p, c, spec, experts=None):
             return apply_layer(x, p, spec, cfg, self.policy,
                                positions=positions, mesh=mesh, cache=c,
                                cache_pos=cache_pos, enc_states=enc_states,
                                shared_params=shared, decode=decode,
                                kv_len=kv_len, esc_fmts=esc_fmts,
                                kv_levels=kv_levels, kv_scale=kv_scale,
-                               verify=verify)
+                               verify=verify, serving=serving,
+                               experts=experts)
+
+        def takes_grouped(spec):
+            return (grouped and spec.ffn == "moe"
+                    and spec.mixer != "shared_attn")
+
+        def one_layer_experts(p, spec):
+            # an unstacked layer's experts as a stack of one
+            if not takes_grouped(spec):
+                return None
+            return tuple(p["mlp"][n][None] for n in _EXPERT_W) + (0,)
 
         for i, spec in enumerate(cfg.prefix):
             c = caches.prefix[i] if caches else None
-            r = run_one(x, params["prefix"][i], c, spec)
+            p = params["prefix"][i]
+            r = run_one(x, p, c, spec, one_layer_experts(p, spec))
             x, nc, aux = r[:3]
             new_prefix.append(nc)
-            aux_total += aux
+            aux_total += aux.astype(aux_total.dtype)
             if esc:
                 flags_total += r[3]
+
+        # grouped: the expert weights leave the scanned params and enter
+        # the body whole; the scan carries the layer index instead
+        pat_params = list(params["pattern"])
+        pat_experts = [None] * len(cfg.pattern)
+        for j, spec in enumerate(cfg.pattern):
+            if takes_grouped(spec):
+                mlp = dict(pat_params[j]["mlp"])
+                pat_experts[j] = tuple(mlp.pop(n) for n in _EXPERT_W)
+                pat_params[j] = {**pat_params[j], "mlp": mlp}
+        n_groups = jax.tree.leaves(params["pattern"])[0].shape[0]
 
         def group_body(carry, xs):
             if esc:
                 h, aux_acc, fl_acc = carry
             else:
                 h, aux_acc = carry
-            gp, gc = xs
+            gp, gc, li = xs
             new_gc = []
             for j, spec in enumerate(cfg.pattern):
                 c = gc[j] if gc is not None else None
-                r = run_one(h, gp[j], c, spec)
+                ex = (pat_experts[j] + (li,) if pat_experts[j] is not None
+                      else None)
+                r = run_one(h, gp[j], c, spec, ex)
                 h, nc, aux = r[:3]
+                aux_acc = aux_acc + aux.astype(aux_acc.dtype)
                 new_gc.append(nc)
                 if esc:
                     fl_acc = fl_acc + r[3]
-            carry = ((h, aux_acc + aux, fl_acc) if esc
-                     else (h, aux_acc + aux))
+            carry = ((h, aux_acc, fl_acc) if esc else (h, aux_acc))
             return carry, (tuple(new_gc) if caches is not None else None)
 
         if remat and cfg.remat_policy == "full":
@@ -653,7 +695,9 @@ class Model:
         carry0 = ((x, aux_total, flags_total) if esc
                   else (x, aux_total))
         fc, new_pat = jax.lax.scan(
-            body, carry0, (params["pattern"], pat_caches),
+            body, carry0,
+            (tuple(pat_params), pat_caches,
+             jnp.arange(n_groups, dtype=jnp.int32)),
             unroll=True if cfg.unroll_scan else 1)
         if esc:
             x, aux_total, flags_total = fc
@@ -662,10 +706,11 @@ class Model:
 
         for i, spec in enumerate(cfg.suffix):
             c = caches.suffix[i] if caches else None
-            r = run_one(x, params["suffix"][i], c, spec)
+            p = params["suffix"][i]
+            r = run_one(x, p, c, spec, one_layer_experts(p, spec))
             x, nc, aux = r[:3]
             new_suffix.append(nc)
-            aux_total += aux
+            aux_total += aux.astype(aux_total.dtype)
             if esc:
                 flags_total += r[3]
 
@@ -1038,7 +1083,7 @@ class Model:
 
     def decode_step(self, params, token, caches: Caches, pos, *, mesh=None,
                     kv_len=None, esc_fmts=None, kv_levels=None,
-                    kv_scale=None):
+                    kv_scale=None, serving: bool = False):
         """One decode step: token [B,1], pos scalar -> (logits [B,1,V],
         caches).  ``pos`` may be a per-sequence [B] vector (ragged batch):
         each row writes its K/V at — and takes its rope position from — its
@@ -1048,7 +1093,9 @@ class Model:
 
         ``esc_fmts``/``kv_levels``/``kv_scale`` (escalation write path, see
         ``attention.quantize_kv_rows``) append the per-row OF/UF write-flag
-        counts ``kv_flags`` [B, 2] to the return."""
+        counts ``kv_flags`` [B, 2] to the return.  ``serving=True`` (from
+        ``decode_round``) takes the serving expert path and appends, last,
+        the number of experts read (``_run_stack``)."""
         cfg = self.cfg
         x = self.embed(params, token, pos_offset=pos if cfg.max_seq else 0)
         if getattr(pos, "ndim", 0) >= 1:
@@ -1059,13 +1106,15 @@ class Model:
                             mesh=mesh, caches=caches,
                             cache_pos=pos, decode=True,
                             kv_len=kv_len, esc_fmts=esc_fmts,
-                            kv_levels=kv_levels, kv_scale=kv_scale)
+                            kv_levels=kv_levels, kv_scale=kv_scale,
+                            serving=serving)
         x, caches = r[0], r[1]
         x = _norm(x, params["norm_f"], cfg)
         lg = self.logits(params, x).astype(F32)
+        ret = (lg, caches)
         if esc_fmts is not None:
-            return lg, caches, r[3]
-        return lg, caches
+            ret += (r[3],)
+        return ret + (r[2],) if serving else ret
 
     # -- continuous-batching steps (launch/engine.py drives these) ---------
     def prefill_chunk(self, params, tokens, caches: Caches, *,
@@ -1120,7 +1169,8 @@ class Model:
                             mesh=mesh, caches=run,
                             cache_pos=q_offset,
                             kv_len=q_offset + live,
-                            esc_fmts=esc_fmts, kv_levels=kv_levels)
+                            esc_fmts=esc_fmts, kv_levels=kv_levels,
+                            serving=True)
         x, run = r[0], r[1]
         x = _norm(x, params["norm_f"], cfg)
         last = (jnp.maximum(jnp.broadcast_to(live, (b,)), 1) - 1)[:, None,
@@ -1159,13 +1209,14 @@ class Model:
         the per-row ``bad`` flag to the return.  ``esc_fmts``/``kv_levels``
         /``kv_scale`` (escalation write path) append the per-row OF/UF
         write-flag counts [B, 2].  Returns ``(next_tok [B,1], logits,
-        caches, key[, bad][, kv_flags])``; the SCHEDULER owns
-        pos/lens/done advancement (see decode_burst for the compiled
+        caches, key[, bad][, kv_flags], experts_read)``; the SCHEDULER
+        owns pos/lens/done advancement (see decode_burst for the compiled
         multi-round form)."""
         attend = jnp.where(done, lens, pos + 1)
         r = self.decode_step(params, tok, caches, pos, mesh=mesh,
                              kv_len=attend, esc_fmts=esc_fmts,
-                             kv_levels=kv_levels, kv_scale=kv_scale)
+                             kv_levels=kv_levels, kv_scale=kv_scale,
+                             serving=True)
         lg, caches = r[0], r[1]
         kv_flags = r[2] if esc_fmts is not None else None
         with jax.named_scope("sample"):
@@ -1193,7 +1244,7 @@ class Model:
             ret += (bad,)
         if esc_fmts is not None:
             ret += (kv_flags,)
-        return ret
+        return ret + (r[-1],)
 
     def decode_burst(self, params, tok, caches: Caches, pos, lens, done,
                      limit, *, max_len: int, out_width: int, n_max,
@@ -1244,11 +1295,14 @@ class Model:
         twin of ``poison_at``.
 
         Returns ``(out [B, out_width], n_steps, tok, caches, pos, lens,
-        done, key[, bad][, counts][, kv_flags])`` — ``out[:, :n_steps]``
-        holds each round's emitted token per row (rows already done emit
-        ``stop_token``/pad); ``bad`` [B] int32 (when ``guard``) counts
-        rounds a live row's logits went non-finite; ``counts`` (when
-        penalties are active) is the advanced histogram."""
+        done, key[, bad][, counts][, kv_flags], experts_read)`` —
+        ``out[:, :n_steps]`` holds each round's emitted token per row
+        (rows already done emit ``stop_token``/pad); ``bad`` [B] int32
+        (when ``guard``) counts rounds a live row's logits went
+        non-finite; ``counts`` (when penalties are active) is the
+        advanced histogram; ``experts_read`` (int32) sums, over the
+        rounds run and the layers, the experts whose weights each MoE
+        layer read (0 for a model without experts)."""
         b = tok.shape[0]
         do_sample = temperature is not None and temperature > 0.0
         if do_sample and key is None:
@@ -1275,8 +1329,8 @@ class Model:
             return more & ((wave == 0) | (newly < wave))
 
         def body(c):
-            i, out, tok, caches, pos, lens, done = c[:7]
-            extra = list(c[7:])
+            i, out, tok, caches, pos, lens, done, n_read = c[:8]
+            extra = list(c[8:])
             cnt = extra.pop(0) if use_pen else None
             badc = extra.pop(0) if guard else None
             flacc = extra.pop(0) if esc else None
@@ -1301,7 +1355,8 @@ class Model:
             new_pos = jnp.where(done, pos,
                                 jnp.minimum(pos + 1, max_len - 1))
             new_lens = jnp.where(done, lens, pos + 1)
-            nc = (i + 1, out, nxt, caches, new_pos, new_lens, fin)
+            nc = (i + 1, out, nxt, caches, new_pos, new_lens, fin,
+                  n_read + r[-1])
             if use_pen:
                 nc += (_bump_counts(cnt, nxt),)
             if guard:
@@ -1312,7 +1367,7 @@ class Model:
                 nc += (flacc + fl * (~done).astype(jnp.int32)[:, None],)
             return nc + ((ky,) if do_sample else ())
 
-        init = (zero, out0, tok, caches, pos, lens, done)
+        init = (zero, out0, tok, caches, pos, lens, done, zero)
         if use_pen:
             init += (counts,)
         if guard:
@@ -1322,8 +1377,8 @@ class Model:
         if do_sample:
             init += (key,)
         fin = jax.lax.while_loop(cond, body, init)
-        n, out, tok, caches, pos, lens, done = fin[:7]
-        extra = list(fin[7:])
+        n, out, tok, caches, pos, lens, done, n_read = fin[:8]
+        extra = list(fin[8:])
         cnt_out = extra.pop(0) if use_pen else None
         bad_out = extra.pop(0) if guard else None
         fl_out = extra.pop(0) if esc else None
@@ -1335,7 +1390,7 @@ class Model:
             ret += (cnt_out,)
         if esc:
             ret += (fl_out,)
-        return ret
+        return ret + (n_read,)
 
     # -- speculative decoding (draft k cheap, verify once, accept prefix) --
     def speculate_check(self):
@@ -1415,7 +1470,7 @@ class Model:
                             kv_len=jnp.broadcast_to(
                                 jnp.asarray(kv_len, jnp.int32), (b, s)),
                             esc_fmts=esc_fmts, kv_levels=kv_levels,
-                            kv_scale=kv_scale)
+                            kv_scale=kv_scale, serving=True)
         x, caches = r[0], r[1]
         x = _norm(x, params["norm_f"], cfg)
         lg = self.logits(params, x).astype(F32)

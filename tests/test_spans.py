@@ -8,7 +8,8 @@ Contract under test:
     the rest as dropped;
   * the engine: ``start()`` resets the recorder; every ``step()`` is one
     ``engine.step`` span whose phases nest inside it in order; the burst
-    spans' ``rounds`` sum to the run's decode rounds; every request gets
+    spans' ``rounds`` sum to the run's decode rounds (and carry the
+    ``experts_read`` counter, 0 for a dense model); every request gets
     one ``engine.queued``, ``engine.first_token`` and ``engine.finish``
     mark, in that order; ``ReplicatedEngine`` keeps one list per replica.
 """
@@ -121,7 +122,8 @@ def test_every_step_is_a_span_with_its_phases_in_order(served):
             assert [k.name for k in sub] == [
                 "engine.burst.prepare", "engine.burst.dispatch",
                 "engine.burst.readback"]
-            assert set(r.attrs) == {"live", "rounds"}
+            assert set(r.attrs) == {"live", "rounds", "experts_read"}
+            assert r.attrs["experts_read"] == 0   # a dense model
         elif r.name == "engine.prefill":
             assert [k.name for k in sub] == ["engine.prefill.readback"]
             assert set(r.attrs) == {"offset", "rows", "tokens"}

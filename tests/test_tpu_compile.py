@@ -15,7 +15,9 @@ be described.  JAX's persistent compilation cache is switched off for the
 duration — a program compiled for a described chip is written to the cache
 but cannot be read back without one.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -163,3 +165,64 @@ def test_interpret_resolves_from_platform():
     assert kops.resolve_interpret(False) is False
     assert kops.resolve_interpret(True) is True
     assert np.isfinite(float(kops.dotp_ex(jnp.ones(4), jnp.ones(4))))
+
+
+# the serving programs at qwen3-moe-30b-a3b widths (128 experts of 2048 x
+# 768, top-8), 10 layers in the scan: the grouped expert kernel takes the
+# stacked [L, E, D, F] weights whole, so no op may copy or slice a tensor
+# whose trailing dims are one expert's matrix
+_EXPERT_OP = re.compile(r"^\s*(?:ROOT )?%(\S+) = \(?\w+\[([\d,]*)\]\S* "
+                        r"([\w-]+)\(")
+
+
+def _expert_weight_copies(txt, d, f):
+    bad = []
+    for line in txt.splitlines():
+        m = _EXPERT_OP.match(line)
+        if not m:
+            continue
+        name, dims, op = m.groups()
+        dims = tuple(int(s) for s in dims.split(",") if s)
+        if dims[-2:] in ((d, f), (f, d)) and (
+                op in ("copy", "dynamic-slice", "slice")
+                or "slice" in name or "copy" in name):
+            bad.append(line.strip()[:160])
+    return bad
+
+
+# (program, policy): the bf16 serving programs, and the float32 prefill
+# chunk that chip_smoke.py's reference runs over the same bf16 weights
+MOE_PROGRAMS = [("decode_burst", "tp_bf16"), ("prefill_chunk", "tp_bf16"),
+                ("prefill_chunk", "fp32")]
+
+
+@pytest.mark.parametrize("program,policy", MOE_PROGRAMS,
+                         ids=["-".join(c) for c in MOE_PROGRAMS])
+def test_moe_serving_reads_stacked_expert_weights(one_chip, monkeypatch,
+                                                  program, policy):
+    from repro.models.registry import build_model
+    from repro.models.transformer import init_caches
+    # trace as on the chip: the platform picks the Pallas kernels and the
+    # grouped expert path
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    model = build_model("qwen3-moe-30b-a3b", policy="tp_bf16").with_cfg(
+        n_layers=10, paged_kv=True, page_size=PAGE)
+    cfg, slots, max_len = model.cfg, 8, 1024
+    on_chip = lambda tree: jax.tree.map(
+        lambda s: _spec(one_chip, s.shape, s.dtype), tree)
+    params = on_chip(jax.eval_shape(lambda: model.init(jax.random.key(0))))
+    model = dataclasses.replace(model, policy=get_policy(policy))
+    caches = on_chip(jax.eval_shape(
+        lambda: init_caches(cfg, slots, max_len, model.policy)))
+    if program == "decode_burst":
+        fn = lambda p, c, pos: model.decode_burst(
+            p, pos[:, None], c, pos, pos, pos < 0, pos + 100,
+            max_len=max_len, out_width=64, n_max=64, exit_on_finish=0,
+            guard=True)[-1]
+    else:
+        fn = lambda p, c, pos: model.prefill_chunk(
+            p, jnp.zeros((1, 128), jnp.int32), c, q_offset=0,
+            row=pos[:1], chunk_lens=pos[:1])[0]
+    txt = _compile(fn, params, caches, _spec(one_chip, (slots,), jnp.int32))
+    assert "grouped_ffn_pallas" in txt
+    assert _expert_weight_copies(txt, cfg.d_model, cfg.moe.d_expert) == []
