@@ -1,22 +1,21 @@
 """internvl2-26b [vlm]: InternViT + InternLM2-20B backbone.
 
 Assignment: 48L d_model=6144 48H (GQA kv=8) d_ff=16384 vocab=92553
-[arXiv:2404.16821; hf].  The ViT frontend is a STUB per the assignment:
-``input_specs()`` provides precomputed patch embeddings that occupy the
-first ``n_frontend_tokens`` positions of the sequence.
+[arXiv:2404.16821; hf].  The backbone's widths are ``internlm2_20b``'s;
+the VLM keeps its own vocabulary.  The ViT frontend is a STUB per the
+assignment: ``input_specs()`` provides precomputed patch embeddings that
+occupy the first ``n_frontend_tokens`` positions of the sequence.
 """
-from .base import LayerSpec, ModelConfig
+import dataclasses
 
-_L = LayerSpec(mixer="gqa", ffn="swiglu")
+from . import internlm2_20b
+from .base import ModelConfig
 
-CONFIG = ModelConfig(
-    name="internvl2-26b", family="vlm",
-    n_layers=48, d_model=6144, n_heads=48, n_kv_heads=8, head_dim=128,
-    d_ff=16384, vocab=92553,
-    pattern=(_L,),
-    rope_theta=1e6, tie_embeddings=False,
+_L = internlm2_20b.CONFIG.pattern[0]
+
+CONFIG = dataclasses.replace(
+    internlm2_20b.CONFIG, name="internvl2-26b", family="vlm", vocab=92553,
     frontend="patch", n_frontend_tokens=256,
-    sub_quadratic=False,
 )
 
 

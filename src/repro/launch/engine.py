@@ -1387,7 +1387,12 @@ class ContinuousEngine:
                     buf[i, :len(piece)] = piece
                     meta[1, i] = len(piece)
                     meta[2, i] = self.kv_levels[b]
-                prefill.set(tokens=int(meta[1].sum()))
+                # (query, key) pairs the wave attends: each row's piece
+                # reads the ``off`` positions before it and itself causally
+                lens = meta[1].astype(np.int64)
+                prefill.set(tokens=int(lens.sum()),
+                            pairs=int((lens * off
+                                       + lens * (lens + 1) // 2).sum()))
                 if self.temperature > 0.0:
                     key, sk = jax.random.split(key)
                     self._key = key
@@ -1576,10 +1581,9 @@ class ContinuousEngine:
                     else:
                         outs = np.asarray(out[:, :n])  # only executed cols
                     bad = np.asarray(bad_d)
-                if self.spec_k:
-                    burst.set(rounds=n)
-                else:
-                    burst.set(rounds=n, experts_read=int(new_state[4, 0]))
+                burst.set(rounds=n)
+                if self.model.cfg.moe is not None and not self.spec_k:
+                    burst.set(experts_read=int(new_state[4, 0]))
             if monitor.record(self._bursts, burst.seconds):
                 counters["stragglers"] += 1
             with spans.span("engine.burst.bookkeeping"):

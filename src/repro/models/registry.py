@@ -9,7 +9,7 @@ from .transformer import Model
 ARCHS = (
     "internvl2_26b", "deepseek_v2_lite_16b", "qwen3_moe_30b_a3b",
     "whisper_small", "xlstm_1_3b", "granite_20b", "gemma2_9b",
-    "minicpm3_4b", "gemma3_12b", "zamba2_1_2b",
+    "minicpm3_4b", "gemma3_12b", "zamba2_1_2b", "internlm2_20b",
 )
 
 # external ids (assignment spelling) -> module names
@@ -25,6 +25,7 @@ ALIASES = {
     "gemma3-12b": "gemma3_12b",
     "zamba2-1.2b": "zamba2_1_2b",
     "fpnew-case-study": "fpnew_case_study",
+    "internlm2-20b": "internlm2_20b",
 }
 
 

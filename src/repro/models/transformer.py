@@ -420,11 +420,13 @@ def apply_layer(x, p, spec: LayerSpec, cfg: ModelConfig,
         h2 = _norm(x, fp["norm2"], cfg)
         if spec.ffn == "swiglu" or (spec.mixer == "shared_attn"
                                     and cfg.shared_block.ffn == "swiglu"):
-            f = swiglu(h2, fp["mlp"]["gate"], fp["mlp"]["up"],
-                       fp["mlp"]["down"], policy)
+            with jax.named_scope("mlp"):
+                f = swiglu(h2, fp["mlp"]["gate"], fp["mlp"]["up"],
+                           fp["mlp"]["down"], policy)
         elif spec.ffn == "gelu":
-            f = gelu_mlp(h2, fp["mlp"]["up"], fp["mlp"]["b_up"],
-                         fp["mlp"]["down"], fp["mlp"]["b_down"], policy)
+            with jax.named_scope("mlp"):
+                f = gelu_mlp(h2, fp["mlp"]["up"], fp["mlp"]["b_up"],
+                             fp["mlp"]["down"], fp["mlp"]["b_down"], policy)
         elif spec.ffn == "moe":
             f, aux = moe_mod.moe_block(h2, fp["mlp"], cfg.moe, policy,
                                        mesh=mesh, serving=serving,

@@ -115,6 +115,7 @@ def test_param_counts_against_public_sizes():
         "qwen3-moe-30b-a3b": (28e9, 32e9),
         "deepseek-v2-lite-16b": (14e9, 17e9),
         "internvl2-26b": (18e9, 22e9),   # LLM backbone of the 26B (ViT stub)
+        "internlm2-20b": (19e9, 21e9),
         "whisper-small": (0.2e9, 0.3e9),
     }
     for arch, (lo, hi) in bands.items():

@@ -9,7 +9,9 @@ Contract under test:
   * the engine: ``start()`` resets the recorder; every ``step()`` is one
     ``engine.step`` span whose phases nest inside it in order; the burst
     spans' ``rounds`` sum to the run's decode rounds (and carry the
-    ``experts_read`` counter, 0 for a dense model); every request gets
+    ``experts_read`` counter only where the model has experts); a
+    prefill wave's ``pairs`` counts the (query, key) pairs its rows
+    attend; every request gets
     one ``engine.queued``, ``engine.first_token`` and ``engine.finish``
     mark, in that order; ``ReplicatedEngine`` keeps one list per replica.
 """
@@ -122,11 +124,10 @@ def test_every_step_is_a_span_with_its_phases_in_order(served):
             assert [k.name for k in sub] == [
                 "engine.burst.prepare", "engine.burst.dispatch",
                 "engine.burst.readback"]
-            assert set(r.attrs) == {"live", "rounds", "experts_read"}
-            assert r.attrs["experts_read"] == 0   # a dense model
+            assert set(r.attrs) == {"live", "rounds"}     # a dense model
         elif r.name == "engine.prefill":
             assert [k.name for k in sub] == ["engine.prefill.readback"]
-            assert set(r.attrs) == {"offset", "rows", "tokens"}
+            assert set(r.attrs) == {"offset", "rows", "tokens", "pairs"}
             assert 0 < r.attrs["tokens"] <= r.attrs["rows"] * eng.chunk
         elif r.name == "engine.admission":
             assert set(r.attrs) == {"pending", "admitted", "preempted"}
@@ -141,6 +142,43 @@ def test_burst_rounds_sum_to_decode_rounds(served):
     assert sum(r.attrs["rounds"] for r in bursts) == stats["decode_rounds"]
     admits = [r for r in stats["spans"] if r.name == "engine.admission"]
     assert sum(r.attrs["admitted"] for r in admits) == 5
+
+
+def test_prefill_pairs_count_each_rows_keys(served):
+    # two rows admitted together (prompts 20 and 28, chunk 16): the wave
+    # at offset 16 carries pieces of 4 and 12 tokens, each reading the
+    # 16 positions before it and itself causally
+    model, params = cached_model("gemma2-9b", paged_kv=True, page_size=16)
+    eng = ContinuousEngine(model, params, slots=2, max_len=48, chunk=16)
+    _, stats = eng.run([Request(rid=i, tokens=[1 + i] * n, max_new=2)
+                        for i, n in enumerate((20, 28))])
+    waves = {(r.attrs["offset"], r.attrs["rows"]): r.attrs
+             for r in stats["spans"] if r.name == "engine.prefill"}
+    assert waves[(0, 2)]["pairs"] == 2 * (16 * 17 // 2)
+    assert waves[(16, 2)]["tokens"] == 4 + 12
+    assert waves[(16, 2)]["pairs"] == (4 * 16 + 4 * 5 // 2) + (
+        12 * 16 + 12 * 13 // 2)
+    # the served fixture's waves: every piece reads at least its offset
+    _, eng, stats = served
+    for r in stats["spans"]:
+        if r.name == "engine.prefill":
+            a = r.attrs
+            assert a["pairs"] >= a["tokens"] * (a["offset"] + 1)
+
+
+def test_moe_bursts_count_the_experts_read():
+    # on the CPU a MoE layer runs the einsum over every expert, so a
+    # burst reads E experts in each layer of each round
+    model, params = cached_model("qwen3-moe-30b-a3b", paged_kv=True,
+                                 page_size=16)
+    eng = ContinuousEngine(model, params, slots=2, max_len=48, chunk=16)
+    _, stats = eng.run(_requests(model.cfg.vocab)[:2])
+    bursts = [r for r in stats["spans"] if r.name == "engine.burst"]
+    per_round = model.cfg.moe.n_experts * model.cfg.n_layers
+    assert bursts
+    for r in bursts:
+        assert set(r.attrs) == {"live", "rounds", "experts_read"}
+        assert r.attrs["experts_read"] == per_round * r.attrs["rounds"]
 
 
 def test_one_queue_mark_per_request_then_first_token_then_finish(served):

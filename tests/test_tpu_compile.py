@@ -109,6 +109,22 @@ def test_paged_flash_prefill_chunk_compiles(one_chip):
              _spec(one_chip, (1,), jnp.int32))
 
 
+def test_paged_flash_prefill_long_chunk_compiles(one_chip):
+    # a wave of 4 slots' 1,024-token chunks at offset 7,168, reading 56
+    # pages of 128 back through the pool (internlm2-20b widths: 48/8 heads
+    # of 128, 8,192-position rows)
+    slots, pages = 4, 64
+    n_pages = slots * pages + 1
+    fn = lambda q, kp, vp, bt, kvl: kops.flash_attention(
+        q, kp, vp, kv_len=kvl, block_table=bt, policy="tp_bf16",
+        q_offset=7168, interpret=False)
+    _compile(fn, _spec(one_chip, (slots, 48, 1024, 128), jnp.bfloat16),
+             _spec(one_chip, (n_pages, 8, PAGE, 128), jnp.bfloat16),
+             _spec(one_chip, (n_pages, 8, PAGE, 128), jnp.bfloat16),
+             _spec(one_chip, (slots, pages), jnp.int32),
+             _spec(one_chip, (slots,), jnp.int32))
+
+
 def test_contiguous_flash_prefill_compiles(one_chip):
     fn = lambda q, k, v, kvl: kops.flash_attention(
         q, k, v, kv_len=kvl, policy="tp_bf16", interpret=False)
