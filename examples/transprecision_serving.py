@@ -10,7 +10,7 @@ kernel (kernels/decode_attention.py).
 Runs a reduced config on CPU; the same code path lowers the decode_32k /
 long_500k dry-run cells on the production meshes.
 
-Run:  PYTHONPATH=src python examples/serve_decode.py [--arch gemma2-9b]
+Run:  PYTHONPATH=src python examples/transprecision_serving.py [--arch gemma2-9b]
 """
 import argparse
 import time

@@ -138,10 +138,8 @@ def resolve_backend(backend: str) -> str:
     """Shared decode/prefill attention-backend resolution.
 
     ``"auto"`` picks the Pallas kernels only off-CPU: on CPU the kernels run
-    in interpret mode, which is ~20x slower than the dense jnp path on the
-    serving hot loop (BENCH_serve.json, gemma2-9b: ``scan_pallas_kv8_tok_s``
-    716 vs ``scan_tok_s`` 14043) — ``auto`` must never silently interpret
-    there.  Explicit ``"pallas"`` is honored anywhere (tests/benchmarks).
+    in interpret mode, which is far slower than the dense jnp path on the
+    serving hot loop — ``auto`` must never silently interpret there.  Explicit ``"pallas"`` is honored anywhere (tests/benchmarks).
     """
     if backend == "auto":
         return "dense" if jax.default_backend() == "cpu" else "pallas"

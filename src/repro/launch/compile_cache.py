@@ -1,7 +1,7 @@
 """JAX's persistent compilation cache, shared by every entry point.
 
 Each entry point (``chip_smoke.py``, ``launch/serve.py``,
-``launch/train.py``, ``benchmarks/serve_decode.py``) calls
+``launch/train.py``) calls
 ``enable_compile_cache()`` before it compiles anything, so a second run in
 the same checkout reloads its XLA programs instead of compiling them again.
 

@@ -5,14 +5,13 @@ def force_dryrun_devices() -> None:
     """Spawn 512 placeholder CPU devices for production-mesh lowering.
 
     MUST run before jax's first backend initialization (jax locks the
-    device count on first init).  Fired automatically when this module is
-    executed as the dry-run tool (``python -m repro.launch.dryrun``), and
-    called explicitly by in-process consumers (benchmarks/perf_report)
-    before they touch jax.  Deliberately NOT a plain-import side effect:
-    importing the parsing helpers from a pytest process must not
-    reconfigure that process's devices — tests must see the real single
-    CPU device (see conftest.py), and the 512-device layout perturbs XLA:CPU
-    codegen enough to break bit-exact kernel-vs-oracle comparisons.
+    device count on first init).  Fired when this module is executed as
+    the dry-run tool (``python -m repro.launch.dryrun``).  Deliberately
+    NOT a plain-import side effect: importing the parsing helpers from a
+    pytest process must not reconfigure that process's devices — tests
+    must see the real single CPU device (see conftest.py), and the
+    512-device layout perturbs XLA:CPU codegen enough to break bit-exact
+    kernel-vs-oracle comparisons.
     """
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
     # the placeholder devices are CPU devices by construction: pin the

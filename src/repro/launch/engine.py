@@ -260,14 +260,14 @@ def _finished_from_record(rec: dict) -> Finished:
 def synthetic_trace(n_req: int, slots: int, prompt_len: int, gen: int,
                     vocab: int, seed: int = 2,
                     flavor: str = "chat") -> List[Request]:
-    """Deterministic workloads for the continuous-batching A/B and the
-    robustness soak.
+    """Deterministic workloads for the engine tests, the serving CLI and
+    the robustness soak.
 
-    ``flavor="chat"`` (default, unchanged): the mixed-length /
-    mixed-budget / mixed-arrival heavy tail of benchmarks/serve_decode.py
-    — every 8th request in the first 3/4 of the queue is LONG (budget
-    ``gen``); the rest cycle short budgets (``gen/16``, ``gen/8``,
-    ``gen/4``).  Prompt lengths cycle 1/4..4/4 of ``prompt_len``.
+    ``flavor="chat"`` (default, unchanged): a mixed-length /
+    mixed-budget / mixed-arrival heavy tail — every 8th request in the
+    first 3/4 of the queue is LONG (budget ``gen``); the rest cycle short
+    budgets (``gen/16``, ``gen/8``, ``gen/4``).  Prompt lengths cycle
+    1/4..4/4 of ``prompt_len``.
     Arrivals: the first ``slots`` requests at round 0, then clumps of
     four every ``gen/16`` rounds.
 

@@ -1,9 +1,7 @@
 """Serving launcher: batched prefill + decode.
 
 The decode loop is a single compiled ``lax.scan`` (``Model.generate``) —
-one XLA dispatch for the whole generation.  ``--loop python`` keeps the
-seed per-step loop (one dispatch per token) for A/B comparison; the
-benchmark in benchmarks/serve_decode.py tracks the two paths over time.
+one XLA dispatch for the whole generation.
 
 ``--ragged`` packs MIXED-length prompts into one right-padded batch (row
 ``b`` gets a length cycling over 1/4, 1/2, 3/4 and 4/4 of ``--prompt-len``)
@@ -119,7 +117,6 @@ def main(argv=None):
     ap.add_argument("--batch", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=64)
     ap.add_argument("--gen", type=int, default=32)
-    ap.add_argument("--loop", choices=("scan", "python"), default="scan")
     ap.add_argument("--decode-backend", choices=("dense", "pallas", "auto"),
                     default="auto",
                     help="pallas: fused in-kernel KV-dequant decode attention;"
@@ -142,14 +139,14 @@ def main(argv=None):
     ap.add_argument("--ragged", action="store_true",
                     help="pack mixed-length prompts (1/4..4/4 of "
                          "--prompt-len) into one padded batch and serve "
-                         "each row at its own length (scan loop only)")
+                         "each row at its own length")
     ap.add_argument("--stop-token", type=int, default=None,
                     help="per-row EOS early-exit: rows freeze after "
-                         "emitting this token id (scan loop only)")
+                         "emitting this token id")
     ap.add_argument("--paged", action="store_true",
                     help="paged KV cache: shared page pool + per-row block "
                          "tables; uniform batches also run the "
-                         "prefix-sharing parity demo (scan loop only)")
+                         "prefix-sharing parity demo")
     ap.add_argument("--page-size", type=int, default=16,
                     help="tokens per KV page (the paged decode kernel's KV "
                          "block; use >= 128 on real TPUs)")
@@ -284,20 +281,12 @@ def main(argv=None):
                          "resumes every unfinished request from its last "
                          "journaled token with bit-identical results")
     args = ap.parse_args(argv)
-    if ((args.ragged or args.paged or args.stop_token is not None
-         or args.continuous) and args.loop != "scan"):
-        ap.error("--ragged / --paged / --stop-token / --continuous require "
-                 "--loop scan (the per-step python loop is the "
-                 "uniform-batch seed path)")
     if args.arrival_trace and not args.continuous:
         args.continuous = True          # a request queue implies the engine
     if args.continuous and args.ragged:
         ap.error("--continuous subsumes --ragged (per-request lengths)")
     pen = (args.repetition_penalty is not None
            or args.presence_penalty is not None)
-    if pen and args.loop != "scan":
-        ap.error("--repetition-penalty / --presence-penalty apply to the "
-                 "scan/while generate() and continuous-engine paths only")
     if args.speculate:
         if not args.continuous:
             ap.error("--speculate requires --continuous (the draft/verify "
@@ -316,8 +305,6 @@ def main(argv=None):
         if dp > 1 and not args.continuous:
             ap.error("--mesh with dp > 1 requires --continuous (the data "
                      "axis is engine replication)")
-        if args.mesh is not None and args.loop != "scan":
-            ap.error("--mesh requires --loop scan")
         mesh_dims = (dp, tp)
     if args.replicas is not None:
         if args.replicas < 1:
@@ -609,58 +596,36 @@ def main(argv=None):
         print(f"paged pool: page={args.page_size}, identity table "
               f"(ragged rows keep private page runs)")
 
-    if args.loop == "scan":
-        key = jax.random.key(args.seed)
-        # guard_nonfinite: every sampling site sanitizes its logits and
-        # counts guarded rows — finite logits pass through bit-identical,
-        # NaN/Inf ones abort serving instead of emitting garbage tokens
-        gen_fn = jax.jit(lambda p, t, pl_, tb: model.generate(
-            p, t, gen_len=args.gen, max_len=max_len,
-            temperature=args.temperature, top_k=args.top_k,
-            top_p=args.top_p, key=key, prompt_lens=pl_,
-            stop_token=args.stop_token, page_table=tb, n_pages=n_pages,
-            repetition_penalty=args.repetition_penalty,
-            presence_penalty=args.presence_penalty,
-            guard_nonfinite=True, mesh=rmesh)[::2])
-        gen, bad = jax.block_until_ready(
-            gen_fn(params, prompts, prompt_lens, page_table))
-        t0 = time.time()
-        gen, bad = jax.block_until_ready(
-            gen_fn(params, prompts, prompt_lens, page_table))
-        dt = time.time() - t0
-        if int(jnp.sum(bad)) > 0:
-            from ..train.fault import PoisonedLogitsError
-            raise PoisonedLogitsError(
-                f"non-finite logits at {int(jnp.sum(bad))} sampling steps "
-                f"(rows {np.nonzero(np.asarray(bad))[0].tolist()})")
-        n_tok = args.batch * args.gen
-        if args.stop_token is not None:
-            live_tok = int(jnp.sum(gen != args.stop_token)
-                           + jnp.sum(jnp.any(gen == args.stop_token, 1)))
-            print(f"stop-token {args.stop_token}: {live_tok}/{n_tok} "
-                  f"tokens live (rest frozen post-EOS)")
-    else:
-        # same sampling rule as the scan path so the A/B stays
-        # apples-to-apples when sampling flags are set
-        from ..models.transformer import sample_token
-        key = jax.random.key(args.seed)
-        pick = jax.jit(lambda lg, k: sample_token(
-            lg, k, temperature=args.temperature, top_k=args.top_k,
-            top_p=args.top_p))
-        prefill = jax.jit(lambda p, t: model.prefill(p, t, max_len=max_len))
-        step = jax.jit(lambda p, t, c, i: model.decode_step(p, t, c, i))
-        lg, caches = prefill(params, prompts)
-        key, sk = jax.random.split(key)
-        tok = pick(lg[:, -1], sk)[:, None]
-        t0 = time.time()
-        for i in range(args.gen - 1):
-            lg, caches = step(params, tok, caches, args.prompt_len + i)
-            key, sk = jax.random.split(key)
-            tok = pick(lg[:, -1], sk)[:, None]
-        jax.block_until_ready(tok)
-        dt = time.time() - t0
-        n_tok = args.batch * (args.gen - 1)
-    tag = f"{args.loop}/{args.decode_backend}" + \
+    key = jax.random.key(args.seed)
+    # guard_nonfinite: every sampling site sanitizes its logits and
+    # counts guarded rows — finite logits pass through bit-identical,
+    # NaN/Inf ones abort serving instead of emitting garbage tokens
+    gen_fn = jax.jit(lambda p, t, pl_, tb: model.generate(
+        p, t, gen_len=args.gen, max_len=max_len,
+        temperature=args.temperature, top_k=args.top_k,
+        top_p=args.top_p, key=key, prompt_lens=pl_,
+        stop_token=args.stop_token, page_table=tb, n_pages=n_pages,
+        repetition_penalty=args.repetition_penalty,
+        presence_penalty=args.presence_penalty,
+        guard_nonfinite=True, mesh=rmesh)[::2])
+    gen, bad = jax.block_until_ready(
+        gen_fn(params, prompts, prompt_lens, page_table))
+    t0 = time.time()
+    gen, bad = jax.block_until_ready(
+        gen_fn(params, prompts, prompt_lens, page_table))
+    dt = time.time() - t0
+    if int(jnp.sum(bad)) > 0:
+        from ..train.fault import PoisonedLogitsError
+        raise PoisonedLogitsError(
+            f"non-finite logits at {int(jnp.sum(bad))} sampling steps "
+            f"(rows {np.nonzero(np.asarray(bad))[0].tolist()})")
+    n_tok = args.batch * args.gen
+    if args.stop_token is not None:
+        live_tok = int(jnp.sum(gen != args.stop_token)
+                       + jnp.sum(jnp.any(gen == args.stop_token, 1)))
+        print(f"stop-token {args.stop_token}: {live_tok}/{n_tok} "
+              f"tokens live (rest frozen post-EOS)")
+    tag = args.decode_backend + \
         (f"/paged{args.page_size}" if args.paged else "")
     print(f"{args.arch} [{tag}]: "
           f"{n_tok} tokens in {dt:.2f}s ({n_tok / dt:.1f} tok/s)")

@@ -15,8 +15,9 @@ plus the continuous-batching steps driven by ``launch/engine.py``:
   ``prefill_chunk``  — one prompt chunk into existing paged caches
   ``decode_round``   — one decode round over every batch slot
   ``decode_burst``   — a ``while_loop`` of rounds, exiting on any finish
-and ``generate(loop="while")``, the early-exit single-shot form (with
-repetition/presence penalties riding the carry — ``apply_penalties``).
+and ``generate``, prefill plus a ``lax.scan`` of decode steps in one
+program (with repetition/presence penalties riding the carry —
+``apply_penalties``): the plain reference the engine is checked against.
 
 Transprecision: every matmul routes through core.ops under the active
 PrecisionPolicy; caches store in ``policy.kv_fmt``; softmax/norm/router
@@ -846,13 +847,10 @@ class Model:
                  page_table=None, n_pages: Optional[int] = None,
                  repetition_penalty: Optional[float] = None,
                  presence_penalty: Optional[float] = None,
-                 loop: str = "scan", return_trips: bool = False,
                  guard_nonfinite: bool = False):
         """Prefill + decode of ``gen_len`` tokens as ONE compiled program:
         the decode loop is a ``lax.scan`` over ``decode_step``, so the whole
-        generation costs a single dispatch instead of one per token (the
-        per-step Python loop pays XLA dispatch + argument flattening ~every
-        token; see benchmarks/serve_decode.py).
+        generation costs a single dispatch instead of one per token.
 
         The cache write index and the attention ``kv_len`` are traced scan
         carries — decode_step (and the Pallas decode kernel, which takes
@@ -896,17 +894,6 @@ class Model:
         (penalized argmax) and EOS freezing alike.  The default (both
         ``None``) carries no count state — greedy stays bit-identical.
 
-        ``loop="while"`` swaps the fixed-trip scan for a
-        ``jax.lax.while_loop`` over the SAME step body: with
-        ``stop_token`` set, the loop exits the round ALL rows are done
-        instead of stepping EOS-frozen rows to ``gen_len`` (trip count
-        capped at ``gen_len - 1`` either way) — tokens are bit-identical
-        to the scan form (unexecuted tail slots are pre-frozen to
-        ``stop_token``), and per-step logits match for every round that
-        actually ran (the tail of ``logits`` is zeros after an early
-        exit).  ``return_trips`` appends the executed decode-round count
-        to the return (``gen_len - 1`` for the scan form).
-
         ``guard_nonfinite=True`` routes every sampling site (prefill
         last-token logits included) through ``sanitize_logits`` and
         appends a per-row [B] int32 count of guarded steps to the return —
@@ -917,12 +904,9 @@ class Model:
 
         Returns ``(gen_tokens [B, gen_len], logits)`` where ``logits`` is
         ``[B, gen_len, V]`` (prefill last-token logits followed by each
-        step's) when ``return_logits`` else None; ``return_trips`` appends
-        the executed decode-round count, ``guard_nonfinite`` appends the
-        per-row guard counts (in that order).
+        step's) when ``return_logits`` else None; ``guard_nonfinite``
+        appends the per-row guard counts.
         """
-        if loop not in ("scan", "while"):
-            raise ValueError(f"loop must be scan|while, got {loop!r}")
         b, prompt_len = tokens.shape
         max_len = max_len if max_len is not None else prompt_len + gen_len
         do_sample = temperature is not None and temperature > 0.0
@@ -1017,70 +1001,13 @@ class Model:
         if guard:
             init.append(bad0.astype(jnp.int32))
 
-        if loop == "while":
-            return self._generate_while(tuple(init), body, tok0, lg0,
-                                        gen_len, use_stop=use_stop,
-                                        stop_token=stop_token,
-                                        return_logits=return_logits,
-                                        return_trips=return_trips,
-                                        return_bad=guard)
         fc, ys = jax.lax.scan(body, tuple(init), None, length=gen_len - 1)
         gen = jnp.concatenate([tok0, ys[0].swapaxes(0, 1)], axis=1)
         lgs = (jnp.concatenate([lg0, jnp.moveaxis(ys[1], 0, 1)], axis=1)
                if return_logits else None)
         out = (gen, lgs)
-        if return_trips:
-            out += (jnp.asarray(gen_len - 1, jnp.int32),)
         if guard:
             out += (fc[-1],)
-        return out
-
-    def _generate_while(self, init, body, tok0, lg0, gen_len: int, *,
-                        use_stop, stop_token, return_logits, return_trips,
-                        return_bad: bool = False):
-        """``generate``'s early-exit form: a ``lax.while_loop`` over the
-        SAME scan step body (bit-parity by construction), exiting the
-        round every row is done.  The token buffer is pre-frozen to
-        ``stop_token``, so unexecuted rounds emit exactly what the scan's
-        frozen rows would have."""
-        b = tok0.shape[0]
-        pad = stop_token if use_stop else 0
-        out0 = jnp.full((b, gen_len), pad, jnp.int32).at[:, 0].set(tok0[:, 0])
-        head = [jnp.zeros((), jnp.int32), out0]
-        if return_logits:
-            head.append(jnp.zeros((b, gen_len, lg0.shape[-1]), F32)
-                        .at[:, 0].set(lg0[:, -1]))
-        n_head = len(head)
-        done_idx = n_head + 4                       # (tok, caches, pos, lens, done)
-
-        def cond(c):
-            more = c[0] < gen_len - 1
-            if use_stop:
-                more = more & ~jnp.all(c[done_idx])
-            return more
-
-        def wbody(c):
-            i = c[0]
-            nc, ys = body(tuple(c[n_head:]), None)
-            out = jax.lax.dynamic_update_slice(c[1], ys[0][:, None],
-                                               (jnp.zeros((), jnp.int32),
-                                                i + 1))
-            head = [i + 1, out]
-            if return_logits:
-                head.append(jax.lax.dynamic_update_slice(
-                    c[2], ys[1][:, None].astype(F32),
-                    (jnp.zeros((), jnp.int32), i + 1,
-                     jnp.zeros((), jnp.int32))))
-            return tuple(head) + nc
-
-        fin = jax.lax.while_loop(cond, wbody, tuple(head) + init)
-        gen, trips = fin[1], fin[0]
-        lgs = fin[2] if return_logits else None
-        out = (gen, lgs)
-        if return_trips:
-            out += (trips,)
-        if return_bad:
-            out += (fin[-1],)     # guard counts ride last in the carry
         return out
 
     def decode_step(self, params, token, caches: Caches, pos, *, mesh=None,

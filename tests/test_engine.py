@@ -2,10 +2,11 @@
 
 Contract under test (the serving-loop analogue of the ragged/paged PRs):
 
-  * while_loop generation — ``generate(loop="while")`` is bit-identical
-    to the scan form (tokens always; logits for executed rounds), exits
-    strictly before ``gen_len - 1`` trips when every row finishes early,
-    and composes with EOS + sampling + penalties in one carry.
+  * the serving while_loop — ``Model.decode_burst``, started from the
+    same prefilled caches, emits exactly the tokens of ``generate``'s
+    scan, leaves after the round every row hit the stop token (strictly
+    below its cap), and composes with EOS + sampling (the key split as
+    ``generate`` splits it) + penalties in one carry.
   * penalties — repetition/presence penalties key off a prompt+emitted
     count histogram, apply before temperature/top-k/top-p, and leave the
     default greedy graph bit-identical.
@@ -27,8 +28,8 @@ from conftest import LENS, cached_model, small_batch
 from repro.launch.engine import ContinuousEngine, Request, synthetic_trace
 from repro.models.paged import PageAllocator
 from repro.models.registry import build_model
-from repro.models.transformer import (apply_penalties, init_caches,
-                                      token_counts)
+from repro.models.transformer import (_bump_counts, apply_penalties,
+                                      init_caches, token_counts)
 
 
 def _setup(policy="tp_bf16", **cfg):
@@ -38,39 +39,85 @@ def _setup(policy="tp_bf16", **cfg):
 
 
 # ---------------------------------------------------------------------------
-# while_loop generation
+# decode_burst (the engine's while_loop) against generate's scan
 # ---------------------------------------------------------------------------
-def test_while_matches_scan_greedy_tokens_and_logits():
-    model, params, toks, _ = _setup()
-    g_s, lg_s = jax.jit(lambda p, t: model.generate(
-        p, t, gen_len=6, max_len=40, return_logits=True))(params, toks)
-    g_w, lg_w, trips = jax.jit(lambda p, t: model.generate(
-        p, t, gen_len=6, max_len=40, return_logits=True, loop="while",
-        return_trips=True))(params, toks)
-    np.testing.assert_array_equal(np.asarray(g_s), np.asarray(g_w))
-    # no stop token: the while form runs the full (capped) trip count and
-    # every per-round logit matches the scan's bitwise
-    assert int(trips) == 5
-    np.testing.assert_array_equal(np.asarray(lg_s), np.asarray(lg_w))
+def _scan_and_burst(model, params, toks, *, gen_len, max_len, lens=None,
+                    stop_token=None, key=None, **sampling):
+    """``generate`` (scan) and ``Model.decode_burst`` from one prefill.
+
+    The burst picks up where the scan's first token left off: the same
+    prefilled caches, the scan's token 0 (EOS-frozen), its count
+    histogram when penalties are on, and the key after ``generate``'s
+    first split.  Returns ``(scan [B, gen_len], burst [B, gen_len],
+    n_steps)``."""
+    b, width = toks.shape
+    key = jax.random.key(0) if key is None else key
+    pen = (sampling.get("repetition_penalty") is not None
+           or sampling.get("presence_penalty") is not None)
+
+    def run(p, t, k):
+        gen = model.generate(p, t, gen_len=gen_len, max_len=max_len,
+                             prompt_lens=lens, stop_token=stop_token, key=k,
+                             **sampling)[0]
+        _, caches = model.prefill(p, t, max_len=max_len, prompt_lens=lens)
+        pos = (jnp.full((b,), width, jnp.int32) if lens is None
+               else jnp.asarray(lens, jnp.int32))
+        tok0 = gen[:, :1]
+        done = (tok0[:, 0] == stop_token if stop_token is not None
+                else jnp.zeros((b,), bool))
+        counts = (_bump_counts(token_counts(t, model.vocab_out, lens), tok0)
+                  if pen else None)
+        out, n = model.decode_burst(
+            p, tok0, caches, pos, pos, done, pos + gen_len - 1,
+            max_len=max_len, out_width=gen_len - 1, n_max=gen_len - 1,
+            exit_on_finish=0, stop_token=stop_token,
+            key=jax.random.split(k)[0], counts=counts, **sampling)[:2]
+        return gen, jnp.concatenate([tok0, out], axis=1), n
+
+    gen, burst, n = jax.jit(run)(params, toks, key)
+    return np.asarray(gen), np.asarray(burst), int(n)
 
 
-def test_while_matches_scan_with_eos_ragged_sampling():
-    model, params, toks, lens = _setup()
-    f = lambda loop: jax.jit(lambda p, t, l, k: model.generate(
-        p, t, gen_len=8, max_len=48, prompt_lens=l, stop_token=3,
-        temperature=0.9, top_k=40, key=k, loop=loop)[0])
-    a = f("scan")(params, toks, lens, jax.random.key(7))
-    b = f("while")(params, toks, lens, jax.random.key(7))
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+def test_burst_matches_scan_greedy_tokens():
+    model, params = cached_model("gemma2-9b", paged_kv=True, page_size=16)
+    toks, _ = small_batch(model.cfg.vocab)
+    g_s, g_b, n = _scan_and_burst(model, params, toks, gen_len=6,
+                                  max_len=48)
+    # no stop token: the burst runs every round up to its cap
+    assert n == 5
+    np.testing.assert_array_equal(g_s, g_b)
 
 
-def test_while_early_exit_trip_count():
-    """All rows hitting EOS at step k must exit the loop with k trips —
-    strictly below the gen_len - 1 cap — with tokens still bit-identical
-    to the scan form (whose frozen tail the while form pre-freezes).
-    (A crushing repetition penalty makes the greedy rollout all-distinct,
-    so any mid-run token is a stop that first fires exactly there.)"""
-    model, params, toks, _ = _setup()
+def _row_one_stop(model, params, toks, **kw):
+    """A stop token that row 1 emits mid-stream and rows 0 and 2 never
+    do, read off a run without one (a stop changes nothing before it
+    fires), so the rows finish at different rounds."""
+    free = _scan_and_burst(model, params, toks, **kw)[0]
+    stop = int(free[1, 2])
+    assert stop not in free[0] and stop not in free[2], free
+    return stop
+
+
+def test_burst_matches_scan_with_eos_ragged_sampling():
+    model, params = cached_model("gemma2-9b", paged_kv=True, page_size=16)
+    toks, lens = small_batch(model.cfg.vocab)
+    kw = dict(gen_len=8, max_len=48, lens=lens, temperature=0.9, top_k=40,
+              key=jax.random.key(7))
+    stop = _row_one_stop(model, params, toks, **kw)
+    g_s, g_b, n = _scan_and_burst(model, params, toks, stop_token=stop,
+                                  **kw)
+    np.testing.assert_array_equal(g_s, g_b)
+    assert (g_b[1, 2:] == stop).all() and n == 7
+
+
+def test_burst_early_exit_round_count():
+    """All rows hitting EOS at round k must leave the burst after k rounds
+    — strictly below its cap — with tokens bit-identical to the scan,
+    whose frozen tail the burst's stop-token pad reproduces.  (A crushing
+    repetition penalty makes the greedy rollout all-distinct, so any
+    mid-run token is a stop that first fires exactly there.)"""
+    model, params = cached_model("gemma2-9b", paged_kv=True, page_size=16)
+    toks, _ = small_batch(model.cfg.vocab)
     toks = jnp.broadcast_to(toks[0:1], (3, 32))         # identical rows
     g0 = np.asarray(jax.jit(lambda p, t: model.generate(
         p, t, gen_len=10, max_len=48, repetition_penalty=1e9)[0])(params,
@@ -78,16 +125,12 @@ def test_while_early_exit_trip_count():
     k = 5
     assert g0[0, k] not in g0[0, :k]                    # all-distinct row
     stop = int(g0[0, k])
-    g_s = np.asarray(jax.jit(lambda p, t: model.generate(
-        p, t, gen_len=10, max_len=48, stop_token=stop,
-        repetition_penalty=1e9)[0])(params, toks))
-    g_w, _, trips = jax.jit(lambda p, t: model.generate(
-        p, t, gen_len=10, max_len=48, stop_token=stop,
-        repetition_penalty=1e9, loop="while",
-        return_trips=True))(params, toks)
-    np.testing.assert_array_equal(g_s, np.asarray(g_w))
-    assert int(trips) == k < 9, (int(trips), k)
-    assert (np.asarray(g_w)[:, k:] == stop).all()
+    g_s, g_b, n = _scan_and_burst(model, params, toks, gen_len=10,
+                                  max_len=48, stop_token=stop,
+                                  repetition_penalty=1e9)
+    np.testing.assert_array_equal(g_s, g_b)
+    assert n == k < 9, (n, k)
+    assert (g_b[:, k:] == stop).all()
 
 
 # ---------------------------------------------------------------------------
@@ -131,17 +174,18 @@ def test_penalties_default_is_bit_identical_and_active_differs():
         assert len(set(g_r[b].tolist())) == g_r.shape[1], g_r[b]
 
 
-def test_penalties_compose_with_sampling_eos_and_while_loop():
-    model, params, toks, lens = _setup()
-    f = lambda loop: jax.jit(lambda p, t, l, k: model.generate(
-        p, t, gen_len=6, max_len=48, prompt_lens=l, stop_token=3,
-        temperature=0.8, top_k=50, key=k, repetition_penalty=1.3,
-        presence_penalty=0.2, loop=loop)[0])
-    s1 = np.asarray(f("scan")(params, toks, lens, jax.random.key(9)))
-    s2 = np.asarray(f("scan")(params, toks, lens, jax.random.key(9)))
-    w1 = np.asarray(f("while")(params, toks, lens, jax.random.key(9)))
+def test_penalties_compose_with_sampling_eos_and_burst():
+    model, params = cached_model("gemma2-9b", paged_kv=True, page_size=16)
+    toks, lens = small_batch(model.cfg.vocab)
+    kw = dict(gen_len=6, max_len=48, lens=lens, temperature=0.8, top_k=50,
+              key=jax.random.key(9), repetition_penalty=1.3,
+              presence_penalty=0.2)
+    stop = _row_one_stop(model, params, toks, **kw)
+    s1, b1, _ = _scan_and_burst(model, params, toks, stop_token=stop, **kw)
+    s2, _, _ = _scan_and_burst(model, params, toks, stop_token=stop, **kw)
     np.testing.assert_array_equal(s1, s2)          # key-deterministic
-    np.testing.assert_array_equal(s1, w1)          # loop-form parity
+    np.testing.assert_array_equal(s1, b1)          # burst == scan
+    assert (b1[1, 2:] == stop).all()
 
 
 # ---------------------------------------------------------------------------

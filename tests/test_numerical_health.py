@@ -366,6 +366,7 @@ def test_escalation_finishes_wider_with_no_poison(esc_setup):
     assert stats["escalations"] >= 1
     assert stats["poisoned_rounds"] == 0
     assert any(f.escalated >= 1 for f in fin)
+    assert len(fin) == len(reqs)
     for r, f in zip(reqs, fin):
         assert len(f.tokens) == r.max_new
     kinds = [k for k, _ in plan.events]
@@ -452,6 +453,7 @@ def test_sdc_detected_and_recovered_bit_exact(swap_setup):
                              n_pages=5, preempt="swap")
     fin_c, stats_c = clean.run(reqs)
     assert stats_c["sdc_injected"] == stats_c["sdc_detected"] == 0
+    assert len(fin) == len(reqs)
     assert [f.tokens for f in fin] == [f.tokens for f in fin_c]
 
 

@@ -429,7 +429,8 @@ def test_ha_soak_session_drains_through_kill(setup):
     """The HA soak: a multi-turn session trace served by a 2-replica
     fleet with a journal, one replica killed mid-run — every request
     (including later turns of the victim's sessions) drains to its full
-    budget on the survivor, nothing stuck, everything journaled."""
+    budget on the survivor with the tokens of the unfailed fleet, nothing
+    stuck, everything journaled."""
     model, _ = setup
     reqs = synthetic_trace(10, 3, 16, 16, model.cfg.vocab,
                            flavor="session")
@@ -448,3 +449,6 @@ def test_ha_soak_session_drains_through_kill(setup):
     assert c["finish"] == len(reqs)
     assert c.get("migrate", 0) == st["ha_migrations"]
     assert c["replica_lost"] == 1
+    base, _ = _fleet(setup, slots=3, max_len=ml, burst_cap=2,
+                     migrate="reingest").run(reqs)
+    assert _toks(fin) == _toks(base)

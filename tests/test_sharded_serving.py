@@ -16,8 +16,13 @@ parity claims they pin:
     token-identical streams with and without a mesh.
 
 The 1-device satellite tests (mesh axis types, divisibility fallback, per-replica allocator isolation, paged cache specs, queue
-partitioning) always run.
+partitioning) always run, and so does
+``test_engine_tp_token_parity_in_an_eight_device_child``, which runs the
+engine's token-parity test in a child process that sees eight devices.
 """
+import os
+import subprocess
+import sys
 import types
 
 import numpy as np
@@ -187,6 +192,18 @@ def test_engine_tp_token_parity():
     base, _ = mk(None).run(reqs)
     tp, _ = mk(tp_mesh(2)).run(reqs)
     assert all(a.tokens == b.tokens for a, b in zip(base, tp))
+
+
+def test_engine_tp_token_parity_in_an_eight_device_child():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
+                        " --xla_force_host_platform_device_count=8").strip()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    r = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         f"{__file__}::test_engine_tp_token_parity"],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0 and "1 passed" in r.stdout, r.stdout[-3000:]
 
 
 @need8
